@@ -600,117 +600,118 @@ def emit_serving(emit, d: dict) -> None:
          value=float(p["tokens_per_s_ratio"]))
 
 
-_SHARDED_SCRIPT = r"""
-import dataclasses, json, os, time
-import numpy as np
-import jax
-from repro import configs
-from repro.models import model as M
-from repro.serve import ServeEngine, Request
+SHARDED_REQ, SHARDED_NEW, SHARDED_PLEN = 8, 16, 9
 
-REQ, NEW, PLEN = 8, 16, 9
-REPEAT = 3
-N_DATA = int(os.environ.get("REPRO_MESH_DATA", "8"))
-N_MODEL = int(os.environ.get("REPRO_MESH_MODEL", "1"))
-NDEV = N_DATA * N_MODEL
-cfg = dataclasses.replace(configs.get_smoke_config("qwen3-1.7b"),
-                          kv_cache_dtype="apack-int8")
-params = M.init_params(configs.get_smoke_config("qwen3-1.7b"),
-                       jax.random.PRNGKey(0))
 
-def build(mb, mesh=None):
-    return ServeEngine(cfg, params, max_batch=mb, max_len=48,
-                       kv_page_size=16, kv_calib_pages=2, mesh=mesh)
+def sharded_run(n_data: int, n_model: int) -> dict:
+    """The body of ``sharded_scenario``: single-device and batch controls,
+    then the ``n_data`` x ``n_model`` mesh engine, over the devices this
+    process holds."""
+    import jax
+    from repro import configs
+    from repro.models import model as M
+    from repro.serve import ServeEngine, Request
 
-def wave(eng, n_req, seed, jit_s=None):
-    rng = np.random.default_rng(seed)
-    reqs = [Request(rid=seed * 1000 + i,
-                    prompt=rng.integers(0, cfg.vocab_size, PLEN)
-                    .astype(np.int32), max_new_tokens=NEW)
-            for i in range(n_req)]
-    for r in reqs:
-        eng.submit(r)
-    eng.step()                        # admission/prefill, untimed
-    steps0 = eng.stats["steps"]
-    d2h = []
-    t0 = time.perf_counter()
-    for _ in range(500):
-        before = eng.kv.transfers["d2h_calls"]
-        n = eng.step()
-        if n == 0 and not eng.queue:
-            break
-        d2h.append(eng.kv.transfers["d2h_calls"] - before)
-    else:
-        raise RuntimeError("engine failed to drain within 500 steps")
-    wall = time.perf_counter() - t0
-    assert all(r.done and r.error is None for r in reqs)
-    steps = max(eng.stats["steps"] - steps0, 1)
-    return {"tokens": [list(r.tokens) for r in reqs],
-            "tok_per_s": n_req * NEW / wall,
-            "s_per_step": wall / steps,
-            "steady_d2h": min(d2h) if d2h else 0}
+    ndev = n_data * n_model
+    base = configs.get_smoke_config("qwen3-1.7b")
+    cfg = dataclasses.replace(base, kv_cache_dtype="apack-int8")
+    params = M.init_params(base, jax.random.PRNGKey(0))
 
-def serve(eng, n_req):
-    wave(eng, n_req, 0)               # warmup eats every jit compile
-    return [wave(eng, n_req, 1 + i) for i in range(REPEAT)]
+    def build(mb, mesh=None):
+        return ServeEngine(cfg, params, max_batch=mb, max_len=48,
+                           kv_page_size=16, kv_calib_pages=2, mesh=mesh)
 
-one = serve(build(1), 1)
-ctrl = serve(build(REQ), REQ)
-mesh = jax.make_mesh((N_DATA, N_MODEL), ("data", "model"))
-eng_s = build(REQ, mesh)
-# wrap the combined sharded step to time its device portion: the host
-# platform executes the per-shard programs back-to-back, so jit/NDEV is
-# the per-shard critical path a real mesh runs concurrently.  Seal work
-# (note_appended: HOT->COLD requantize, APack encode, fused plane
-# scatter) is per-PAGE host work — pages are owned by shards, so on a
-# real multi-host mesh each host seals only its own shards' pages and
-# this bucket divides by NDEV too; only engine bookkeeping
-# (retire/admit, step meta, token pull) stays serialized
-jit_acc = {"s": 0.0, "n": 0}
-seal_acc = {"s": 0.0}
-orig = eng_s._step_mesh
-def timed_step(*a):
-    t0 = time.perf_counter()
-    out = orig(*a)
-    jax.block_until_ready(out[0])
-    jit_acc["s"] += time.perf_counter() - t0
-    jit_acc["n"] += 1
-    return out
-eng_s._step_mesh = timed_step
-orig_note = eng_s.kv.note_appended
-def timed_note(*a, **k):
-    t0 = time.perf_counter()
-    r = orig_note(*a, **k)
-    seal_acc["s"] += time.perf_counter() - t0
-    return r
-eng_s.kv.note_appended = timed_note
-wave(eng_s, REQ, 0)                   # warmup eats every jit compile
-jit_acc["s"], jit_acc["n"] = 0.0, 0   # count compile-free steps only
-seal_acc["s"] = 0.0
-sh = [wave(eng_s, REQ, 1 + i) for i in range(REPEAT)]
+    def wave(eng, n_req, seed):
+        rng = np.random.default_rng(seed)
+        reqs = [Request(rid=seed * 1000 + i,
+                        prompt=rng.integers(0, cfg.vocab_size, SHARDED_PLEN)
+                        .astype(np.int32), max_new_tokens=SHARDED_NEW)
+                for i in range(n_req)]
+        for r in reqs:
+            eng.submit(r)
+        eng.step()                    # admission/prefill, untimed
+        steps0 = eng.stats["steps"]
+        d2h = []
+        t0 = time.perf_counter()
+        for _ in range(500):
+            before = eng.kv.transfers["d2h_calls"]
+            n = eng.step()
+            if n == 0 and not eng.queue:
+                break
+            d2h.append(eng.kv.transfers["d2h_calls"] - before)
+        else:
+            raise RuntimeError("engine failed to drain within 500 steps")
+        wall = time.perf_counter() - t0
+        assert all(r.done and r.error is None for r in reqs)
+        steps = max(eng.stats["steps"] - steps0, 1)
+        return {"tokens": [list(r.tokens) for r in reqs],
+                "tok_per_s": n_req * SHARDED_NEW / wall,
+                "s_per_step": wall / steps,
+                "steady_d2h": min(d2h) if d2h else 0}
 
-identical = all(w_s["tokens"] == w_c["tokens"]
-                for w_s, w_c in zip(sh, ctrl))
-t1 = min(w["s_per_step"] for w in one)
-tc = min(w["s_per_step"] for w in ctrl)
-ts = min(w["s_per_step"] for w in sh)
-ts_jit = jit_acc["s"] / max(jit_acc["n"], 1)
-ts_seal = seal_acc["s"] / max(jit_acc["n"], 1)
-serial = max(ts - ts_jit - ts_seal, 0.0)
-parallel_step = ts_jit / NDEV + ts_seal / NDEV + serial
-print(json.dumps({
-    "mesh": f"{N_DATA}x{N_MODEL}",
-    "tok_per_s_single": max(w["tok_per_s"] for w in one),
-    "tok_per_s_sharded": max(w["tok_per_s"] for w in sh),
-    "s_per_step_single": t1, "s_per_step_batch": tc,
-    "s_per_step_sharded": ts, "s_per_step_jit": ts_jit,
-    "s_per_step_seal": ts_seal, "s_per_step_serial": serial,
-    "scaling_serialized_x": REQ * t1 / ts,
-    "scaling_x": REQ * t1 / parallel_step,
-    "step_overhead_x": ts / tc,
-    "token_identity": bool(identical),
-    "steady_d2h_calls": max(w["steady_d2h"] for w in sh)}))
-"""
+    def serve(eng, n_req):
+        wave(eng, n_req, 0)           # warmup eats every jit compile
+        return [wave(eng, n_req, 1 + i) for i in range(REPEAT)]
+
+    one = serve(build(1), 1)
+    ctrl = serve(build(SHARDED_REQ), SHARDED_REQ)
+    mesh = jax.make_mesh((n_data, n_model), ("data", "model"),
+                         devices=jax.devices()[:ndev])
+    eng_s = build(SHARDED_REQ, mesh)
+    # wrap the combined sharded step to time its device portion: the host
+    # platform executes the per-shard programs back-to-back, so jit/ndev
+    # is the per-shard critical path a real mesh runs concurrently.  Seal
+    # work (note_appended: HOT->COLD requantize, APack encode, fused plane
+    # scatter) is per-PAGE host work — pages are owned by shards, so on a
+    # real multi-host mesh each host seals only its own shards' pages and
+    # this bucket divides by ndev too; only engine bookkeeping
+    # (retire/admit, step meta, token pull) stays serialized
+    jit_acc = {"s": 0.0, "n": 0}
+    seal_acc = {"s": 0.0}
+    orig = eng_s._step_mesh
+
+    def timed_step(*a):
+        t0 = time.perf_counter()
+        out = orig(*a)
+        jax.block_until_ready(out[0])
+        jit_acc["s"] += time.perf_counter() - t0
+        jit_acc["n"] += 1
+        return out
+    eng_s._step_mesh = timed_step
+    orig_note = eng_s.kv.note_appended
+
+    def timed_note(*a, **k):
+        t0 = time.perf_counter()
+        r = orig_note(*a, **k)
+        seal_acc["s"] += time.perf_counter() - t0
+        return r
+    eng_s.kv.note_appended = timed_note
+    wave(eng_s, SHARDED_REQ, 0)       # warmup eats every jit compile
+    jit_acc["s"], jit_acc["n"] = 0.0, 0   # count compile-free steps only
+    seal_acc["s"] = 0.0
+    sh = [wave(eng_s, SHARDED_REQ, 1 + i) for i in range(REPEAT)]
+
+    identical = all(w_s["tokens"] == w_c["tokens"]
+                    for w_s, w_c in zip(sh, ctrl))
+    t1 = min(w["s_per_step"] for w in one)
+    tc = min(w["s_per_step"] for w in ctrl)
+    ts = min(w["s_per_step"] for w in sh)
+    ts_jit = jit_acc["s"] / max(jit_acc["n"], 1)
+    ts_seal = seal_acc["s"] / max(jit_acc["n"], 1)
+    serial = max(ts - ts_jit - ts_seal, 0.0)
+    parallel_step = ts_jit / ndev + ts_seal / ndev + serial
+    return {
+        "mesh": f"{n_data}x{n_model}",
+        "tok_per_s_single": max(w["tok_per_s"] for w in one),
+        "tok_per_s_sharded": max(w["tok_per_s"] for w in sh),
+        "s_per_step_single": t1, "s_per_step_batch": tc,
+        "s_per_step_sharded": ts, "s_per_step_jit": ts_jit,
+        "s_per_step_seal": ts_seal, "s_per_step_serial": serial,
+        "scaling_serialized_x": SHARDED_REQ * t1 / ts,
+        "scaling_x": SHARDED_REQ * t1 / parallel_step,
+        "step_overhead_x": ts / tc,
+        "token_identity": bool(identical),
+        "steady_d2h_calls": max(w["steady_d2h"] for w in sh)}
 
 
 def sharded_scenario(devices: int | None = None,
@@ -718,9 +719,14 @@ def sharded_scenario(devices: int | None = None,
     """Mesh-sharded serving scaling row (DESIGN.md §11): a
     ``mesh_shape`` = (data, model) engine — default 8x1, pure data
     parallel; the ``--mesh`` CLI flag selects e.g. 4x2 for kv-head
-    tensor parallelism — on a forced multi-device host platform vs the
-    single-device engine, in a subprocess so the XLA device-count flag
-    never leaks into this process (whose smoke rows must see 1 device).
+    tensor parallelism — vs the single-device engine.
+
+    On an accelerator ``sharded_run`` runs in this process, over the
+    devices it already holds (a child could not reach a chip this
+    process holds), and fails when there are too few.  On the CPU it
+    rehearses on a forced multi-device host platform, in a subprocess so
+    the XLA device-count flag never leaks into this process (whose smoke
+    rows must see 1 device); the child writes its result to a file.
 
     Reports aggregate tokens/s, greedy-token bit-identity against the
     single-device control serving the same waves, the per-shard
@@ -739,21 +745,37 @@ def sharded_scenario(devices: int | None = None,
     import os
     import subprocess
     import sys
+    import tempfile
     from pathlib import Path
+
+    import jax
 
     n_data, n_model = mesh_shape
     devices = devices or n_data * n_model
+    if jax.default_backend() != "cpu":
+        held = len(jax.devices())
+        if held < n_data * n_model:
+            raise RuntimeError(
+                f"sharded scenario needs {n_data * n_model} devices for "
+                f"its {n_data}x{n_model} mesh; this process holds {held}")
+        return sharded_run(n_data, n_model)
+    root = Path(__file__).resolve().parent.parent
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
-    env["REPRO_MESH_DATA"] = str(n_data)
-    env["REPRO_MESH_MODEL"] = str(n_model)
-    env["PYTHONPATH"] = str(Path(__file__).resolve().parent.parent / "src")
-    out = subprocess.run([sys.executable, "-c", _SHARDED_SCRIPT], env=env,
-                         capture_output=True, text=True, timeout=1800)
-    if out.returncode != 0:
-        raise RuntimeError(f"sharded scenario subprocess failed:\n"
-                           f"{out.stdout}\n{out.stderr}")
-    return json.loads(out.stdout.strip().splitlines()[-1])
+    env["PYTHONPATH"] = f"{root}{os.pathsep}{root / 'src'}"
+    with tempfile.TemporaryDirectory() as tmp:
+        result = Path(tmp) / "sharded.json"
+        code = ("import json, sys\n"
+                "from benchmarks.bench_decode import sharded_run\n"
+                f"d = sharded_run({n_data}, {n_model})\n"
+                "open(sys.argv[1], 'w').write(json.dumps(d))\n")
+        out = subprocess.run([sys.executable, "-c", code, str(result)],
+                             env=env, capture_output=True, text=True,
+                             timeout=1800)
+        if out.returncode != 0:
+            raise RuntimeError(f"sharded scenario subprocess failed:\n"
+                               f"{out.stdout}\n{out.stderr}")
+        return json.loads(result.read_text())
 
 
 def emit_sharded(emit, d: dict) -> None:

@@ -23,6 +23,9 @@ def main(argv=None) -> None:
                     help="run only sections whose label contains SUBSTR")
     args = ap.parse_args(argv)
 
+    import jax
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import (bench_distributions, bench_tablegen, bench_traffic,
                    bench_energy, bench_speedup, bench_codec, bench_decode,
                    bench_roofline, bench_trained, bench_analysis)
@@ -70,6 +73,9 @@ def main(argv=None) -> None:
         doc = {
             "schema": "apack-bench-v1",
             "platform": platform.platform(),
+            "device": {"platform": jax.devices()[0].platform,
+                       "kind": jax.devices()[0].device_kind,
+                       "count": len(jax.devices())},
             "python": platform.python_version(),
             "results": rows,
         }

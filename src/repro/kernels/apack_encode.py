@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.ac_golden import MAX_PENDING, PCOUNT_BITS, QUARTER, TOP
+from .apack_decode import resolve_interpret
 from .ref import (encode_renorm, ofs_capacity_words, shl32, shr32,
                   sym_capacity_words)
 
@@ -144,11 +145,12 @@ def _encode_kernel(values_ref, vmin_ref, ol_ref, cum_ref,
 def encode_pallas(values: jax.Array, v_min: jax.Array, ol: jax.Array,
                   cum: jax.Array, *, n_steps: int, bits: int = 8,
                   block_streams: int = BLOCK_STREAMS,
-                  interpret: bool = True):
+                  interpret: bool | None = None):
     """AC-encode S streams of values i32[S, E].  S % block_streams == 0.
 
     Returns (sym_plane u32[Ws,S], ofs_plane u32[Wo,S], sym_bits, ofs_bits,
-    overflow) — identical contract to ``ref.encode_ac``."""
+    overflow) — identical contract to ``ref.encode_ac``.  ``interpret=None``
+    takes the platform's choice (``ops._default_backend``)."""
     s, e = values.shape
     assert e == n_steps and s % block_streams == 0
     ws = sym_capacity_words(n_steps)
@@ -178,6 +180,6 @@ def encode_pallas(values: jax.Array, v_min: jax.Array, ol: jax.Array,
             jax.ShapeDtypeStruct((s,), I32),
             jax.ShapeDtypeStruct((s,), I32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(values.astype(I32), v_min.astype(I32), ol.astype(I32), cum.astype(I32))
     return outs

@@ -4,9 +4,9 @@ This is the TPU materialization of the paper's Figure 1: the accelerator's
 compute units (here: the MXU ``jnp.dot``) consume *decompressed* values that
 never exist in off-chip memory.  The weight matrix lives in HBM as
 word-interleaved APack planes; each grid step DMAs one compressed tile's
-slot into VMEM (BlockSpec), lane-decodes it (``decode_block``), dequantizes,
-and feeds the MXU — so HBM traffic for weights is the compressed footprint,
-exactly the saving the paper's memory-controller codec achieves.
+slot into VMEM (BlockSpec), lane-decodes it (``decode_block``), feeds the
+int8 codes to the MXU and dequantizes the accumulated product, so the
+decompressed weights never reach HBM.
 
 Weight layout: W[K, N] is tiled into (K // E) x (N // NS) tiles; stream
 ``c`` of tile (k, j) holds column ``j*NS + c`` over rows ``k*E..(k+1)*E``.
@@ -30,7 +30,8 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import format as fmt
 from repro.core import quant
 from repro.core.tables import ApackTable, find_table, histogram
-from .apack_decode import decode_block
+from .apack_decode import (SMEM_SPEC, decode_block, resolve_interpret,
+                           table_scalars)
 from . import ref as _ref
 
 I32 = jnp.int32
@@ -176,18 +177,24 @@ def _fused_kernel(x_ref, sym_ref, ofs_ref, stored_ref, vmin_ref, ol_ref,
 
     # The grid iterates M innermost, so each compressed weight tile (j, kt)
     # is decoded exactly once — at its first row-block visit — and the
-    # dequantized tile persists in VMEM scratch for the remaining
+    # tile's int8 codes persist in VMEM scratch for the remaining
     # m_pad // block_m - 1 visits (EIE-style decode-once amortization).
     @pl.when(i == 0)
     def _decode_tile():
-        vals = decode_block(sym_ref[...].astype(U32), ofs_ref[...].astype(U32),
-                            stored_ref[...] != 0, vmin_ref[...], ol_ref[...],
-                            cum_ref[...], n_steps=tile_k, bits=8)   # [NS, E]
-        # two's-complement reinterpret + per-channel dequant
-        signed = jnp.where(vals >= 128, vals - 256, vals).astype(jnp.float32)
-        w_tile_ref[...] = signed.T * scale_ref[...][None, :]   # [E, NS] f32
+        def signed(vals):               # two's-complement reinterpret
+            return jnp.where(vals >= 128, vals - 256, vals).astype(
+                jnp.float32)
 
+        # decoded rows land as [E, NS]: the tile's K-major layout
+        decode_block(sym_ref, ofs_ref, stored_ref[...] != 0,
+                     table_scalars(vmin_ref, ol_ref, cum_ref), w_tile_ref,
+                     n_steps=tile_k, bits=8, post=signed)
+
+    # The codes are exact in every MXU pass and HIGHEST keeps x at f32
+    # precision (the default takes one bf16 pass), so each product is
+    # exact and only the f32 summation order can differ from the host.
     part = jnp.dot(x_ref[...].astype(jnp.float32), w_tile_ref[...],
+                   precision=jax.lax.Precision.HIGHEST,
                    preferred_element_type=jnp.float32)
 
     # Accumulate in a VMEM scratch strip, not in out_ref: the out-block
@@ -196,8 +203,8 @@ def _fused_kernel(x_ref, sym_ref, ofs_ref, stored_ref, vmin_ref, ol_ref,
     # prior contents for *consecutive* grid steps.  Scratch persists for
     # the whole kernel, so the strip holds each row-block's running sum
     # across the interleaved visits; out_ref is written exactly once, at
-    # the final K-tile.
-    rows = pl.ds(i * block_m, block_m)
+    # the final K-tile, with the per-channel dequant scale applied there.
+    rows = pl.ds(pl.multiple_of(i * block_m, block_m), block_m)
 
     @pl.when(kt == 0)
     def _init():
@@ -209,12 +216,13 @@ def _fused_kernel(x_ref, sym_ref, ofs_ref, stored_ref, vmin_ref, ol_ref,
 
     @pl.when(kt == nk - 1)
     def _flush():
-        out_ref[...] = acc_ref[rows, :]
+        out_ref[...] = acc_ref[rows, :] * scale_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_m"))
 def compressed_matmul(x: jax.Array, cw: CompressedLinear,
-                      interpret: bool = True, block_m: int = 256) -> jax.Array:
+                      interpret: bool | None = None,
+                      block_m: int = 256) -> jax.Array:
     """``x @ W`` where W is APack-compressed; x: f32/bf16 [M, K].
 
     Grid order is (N-tiles, K-tiles, M-blocks) with M innermost: decode work
@@ -225,8 +233,14 @@ def compressed_matmul(x: jax.Array, cw: CompressedLinear,
     Partial products accumulate in a VMEM scratch strip [m_pad, TILE_N]
     and flush to the output block exactly once, at kt == nk - 1, so the
     kernel never relies on Mosaic preserving a revisited output block
-    across non-consecutive grid steps — safe for compiled TPU mode, and
-    bit-identical to interpret mode (same kt-major summation order)."""
+    across non-consecutive grid steps.  The dot multiplies the exact
+    int8 codes at HIGHEST precision and the per-channel scale is applied
+    once, at the flush, so compiled and interpret mode differ only in
+    the f32 summation order inside a K-tile; a product of exactly
+    representable partial sums (``x`` = identity) is bit-exact in both.
+
+    ``interpret=None`` takes the platform's choice (``ops._default_backend``).
+    """
     m, k = x.shape
     assert k == cw.k, f"K mismatch: {k} vs {cw.k}"
     k_pad, n_pad = cw.k_pad, cw.n_pad
@@ -242,19 +256,18 @@ def compressed_matmul(x: jax.Array, cw: CompressedLinear,
             pl.BlockSpec((block_m, cw.tile_k), lambda j, kt, i: (i, kt)),
             pl.BlockSpec((ws, TILE_N), lambda j, kt, i: (0, kt * nn + j)),
             pl.BlockSpec((wo, TILE_N), lambda j, kt, i: (0, kt * nn + j)),
-            pl.BlockSpec((TILE_N,), lambda j, kt, i: (kt * nn + j,)),
-            pl.BlockSpec((17,), lambda j, kt, i: (0,)),
-            pl.BlockSpec((16,), lambda j, kt, i: (0,)),
-            pl.BlockSpec((17,), lambda j, kt, i: (0,)),
-            pl.BlockSpec((TILE_N,), lambda j, kt, i: (j,)),
+            pl.BlockSpec((1, TILE_N), lambda j, kt, i: (0, kt * nn + j)),
+            SMEM_SPEC, SMEM_SPEC, SMEM_SPEC,
+            pl.BlockSpec((1, TILE_N), lambda j, kt, i: (0, j)),
         ],
         out_specs=pl.BlockSpec((block_m, TILE_N), lambda j, kt, i: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m_pad, n_pad), jnp.float32),
         scratch_shapes=[pltpu.VMEM((cw.tile_k, TILE_N), jnp.float32),
                         pltpu.VMEM((m_pad, TILE_N), jnp.float32)],
-        interpret=interpret,
-    )(xp, cw.sym_plane, cw.ofs_plane, cw.stored, cw.v_min, cw.ol, cw.cum,
-      cw.scale)
+        interpret=resolve_interpret(interpret),
+        name="compressed_matmul",
+    )(xp, cw.sym_plane, cw.ofs_plane, cw.stored.reshape(1, -1), cw.v_min,
+      cw.ol, cw.cum, cw.scale.reshape(1, -1))
     return out[:m, :cw.n]
 
 

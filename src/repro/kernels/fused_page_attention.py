@@ -37,9 +37,9 @@ current token's self-attention term before dividing.
 
 Interpret mode is the validated contract on CPU (bit-identical to the
 pure-jnp ``fused_page_attention_ref``); the same kernel compiles on TPU
-with the pool planes resident in HBM.  The output block for a job is
-revisited across the page-innermost grid steps — the same Mosaic revisit
-caveat as ``decompress_matmul`` applies before enabling compiled mode.
+with the pool planes resident in HBM.  The online-softmax state lives in
+VMEM scratch across a job's page-innermost grid steps and is written to
+the output block once, at the job's last page.
 """
 from __future__ import annotations
 
@@ -51,7 +51,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import ref as _ref
-from .apack_decode import decode_block
+from .apack_decode import decode_block, resolve_interpret, table_scalars
 
 I32 = jnp.int32
 U32 = jnp.uint32
@@ -65,17 +65,18 @@ NEG_INF = -1e30          # same mask value as the dense attention paths
 
 
 def _page_tile(state, h0, tok_ref, tok_s_ref, cold_ref, pscale_ref, sym_ref,
-               ofs_ref, stored_ref, vm_ref, ol_ref, cum_ref, tile_ref, *,
-               ps, h, dh, h_full, n_steps, bits):
+               ofs_ref, stored_ref, vm_ref, ol_ref, cum_ref, tile_ref,
+               dec_ref, full_ref, *, ps, h, dh, h_full, n_steps, bits):
     """Fill ``tile_ref`` ([ps, H, dh] f32 VMEM scratch) with the
     dequantized K or V payload of the current page, by lifecycle state.
 
     Under head tensor-parallelism the dense planes hold only this shard's
     ``h`` heads but a PACKED page always decodes all ``h_full`` heads —
     the APack streams interleave heads, so the compressed payload cannot
-    be split — and the shard's block is sliced out at the traced ``h0``
-    offset (0 and ``h == h_full`` on a single device: the slice is the
-    identity)."""
+    be split — and the shard's block is read out of ``full_ref`` at the
+    traced ``h0`` offset (``full_ref`` is None on a single device, where
+    ``h == h_full``)."""
+    scale = pscale_ref[0][None]                              # [1, H, 1]
 
     @pl.when(state == PAGE_HOT)
     def _hot():
@@ -84,18 +85,20 @@ def _page_tile(state, h0, tok_ref, tok_s_ref, cold_ref, pscale_ref, sym_ref,
 
     @pl.when(state == PAGE_COLD)
     def _cold():
-        tile_ref[...] = (cold_ref[0].astype(F32)
-                         * pscale_ref[0].astype(F32)[None, :, None])
+        tile_ref[...] = cold_ref[0].astype(F32) * scale
 
     @pl.when(state == PAGE_PACKED)
     def _packed():
-        u = decode_block(sym_ref[0].astype(U32), ofs_ref[0].astype(U32),
-                         stored_ref[0] != 0, vm_ref[0], ol_ref[0],
-                         cum_ref[0], n_steps=n_steps, bits=bits)
+        decode_block(sym_ref.at[0], ofs_ref.at[0], stored_ref[0] != 0,
+                     table_scalars(vm_ref, ol_ref, cum_ref, 0, 0), dec_ref,
+                     n_steps=n_steps, bits=bits)
+        u = dec_ref[...].T                                  # [S, E]
         signed = jnp.where(u >= 128, u - 256, u).astype(F32)
-        local = jax.lax.dynamic_slice_in_dim(
-            signed.reshape(ps, h_full, dh), h0, h, axis=1)
-        tile_ref[...] = local * pscale_ref[0].astype(F32)[None, :, None]
+        signed = signed.reshape(ps, h_full, dh)
+        if full_ref is not None:
+            full_ref[...] = signed
+            signed = full_ref[:, pl.ds(h0, h), :]
+        tile_ref[...] = signed * scale
 
 
 def _fused_kernel(idx_ref, tid_ref, q_ref, jm_ref, meta_ref,
@@ -106,10 +109,11 @@ def _fused_kernel(idx_ref, tid_ref, q_ref, jm_ref, meta_ref,
                   vm_k_ref, ol_k_ref, cum_k_ref,
                   vm_v_ref, ol_v_ref, cum_v_ref,
                   acc_ref, m_ref, l_ref,
-                  kt_ref, vt_ref, acc_s, m_s, l_s, *,
+                  kt_ref, vt_ref, acc_s, m_s, l_s, dec_ref, *full_ref,
                   ps: int, hkv: int, g: int, dh: int, h_full: int,
                   n_steps: int, bits: int, softcap: float):
     del idx_ref, tid_ref                 # consumed by BlockSpec index_maps
+    full_ref = full_ref[0] if full_ref else None
     p = pl.program_id(1)
     n_pages = pl.num_programs(1)
 
@@ -121,33 +125,33 @@ def _fused_kernel(idx_ref, tid_ref, q_ref, jm_ref, meta_ref,
 
     state = meta_ref[0, 0, 0]
     t0 = meta_ref[0, 0, 1]
-    qpos = jm_ref[0, 0]
-    window = jm_ref[0, 1]
-    h0 = jm_ref[0, 2]
+    qpos = jm_ref[0, 0, 0]
+    window = jm_ref[0, 0, 1]
+    h0 = jm_ref[0, 0, 2]
 
-    _page_tile(state, h0, tok_k_ref, tok_sk_ref, cold_k_ref, pscale_k_ref,
-               sym_k_ref, ofs_k_ref, st_k_ref, vm_k_ref, ol_k_ref,
-               cum_k_ref, kt_ref, ps=ps, h=hkv, dh=dh, h_full=h_full,
-               n_steps=n_steps, bits=bits)
-    _page_tile(state, h0, tok_v_ref, tok_sv_ref, cold_v_ref, pscale_v_ref,
-               sym_v_ref, ofs_v_ref, st_v_ref, vm_v_ref, ol_v_ref,
-               cum_v_ref, vt_ref, ps=ps, h=hkv, dh=dh, h_full=h_full,
-               n_steps=n_steps, bits=bits)
+    fill_tile = functools.partial(
+        _page_tile, state, h0, dec_ref=dec_ref, full_ref=full_ref, ps=ps,
+        h=hkv, dh=dh, h_full=h_full, n_steps=n_steps, bits=bits)
+    fill_tile(tok_k_ref, tok_sk_ref, cold_k_ref, pscale_k_ref, sym_k_ref,
+              ofs_k_ref, st_k_ref, vm_k_ref, ol_k_ref, cum_k_ref, kt_ref)
+    fill_tile(tok_v_ref, tok_sv_ref, cold_v_ref, pscale_v_ref, sym_v_ref,
+              ofs_v_ref, st_v_ref, vm_v_ref, ol_v_ref, cum_v_ref, vt_ref)
 
-    q = q_ref[0].reshape(hkv, g, dh).astype(F32)
+    q = q_ref[0].astype(F32)                                 # [H, g, dh]
     k_tile = kt_ref[...]                                     # [ps, H, dh]
     v_tile = vt_ref[...]
     scores = jnp.einsum("kgd,skd->kgs", q, k_tile) * (dh ** -0.5)
-    pos = t0 + jnp.arange(ps, dtype=I32)
-    valid = (pos < qpos) & (state != PAGE_FREE)
-    valid &= jnp.where(window > 0, pos > qpos - window, True)
-    scores = jnp.where(valid[None, None, :], scores, NEG_INF)
+    pos = t0 + jax.lax.broadcasted_iota(I32, (1, 1, ps), 2)
+    # window == 0 means global: every position clears the lower bound
+    lower = jnp.where(window > 0, qpos - window, t0 - 1)
+    valid = (pos < qpos) & (pos > lower) & (state != PAGE_FREE)
+    scores = jnp.where(valid, scores, NEG_INF)
     if softcap > 0:
         scores = softcap * jnp.tanh(scores / softcap)
     m_new = jnp.maximum(m_s[...], jnp.max(scores, axis=-1))
     # explicit * valid: with a fully-masked page m stays at NEG_INF and
     # exp(NEG_INF - NEG_INF) == 1 would otherwise pollute l
-    w = jnp.exp(scores - m_new[..., None]) * valid[None, None, :]
+    w = jnp.exp(scores - m_new[..., None]) * valid.astype(F32)
     alpha = jnp.exp(m_s[...] - m_new)
     l_s[...] = l_s[...] * alpha + jnp.sum(w, axis=-1)
     acc_s[...] = (acc_s[...] * alpha[..., None]
@@ -156,9 +160,9 @@ def _fused_kernel(idx_ref, tid_ref, q_ref, jm_ref, meta_ref,
 
     @pl.when(p == n_pages - 1)
     def _flush():
-        acc_ref[0] = acc_s[...].reshape(hkv * g, dh)
-        m_ref[0] = m_s[...].reshape(hkv * g)
-        l_ref[0] = l_s[...].reshape(hkv * g)
+        acc_ref[0] = acc_s[...]
+        m_ref[0] = m_s[...]
+        l_ref[0] = l_s[...]
 
 
 # apack: allow-jit-cache(softcap is one value per served model config --
@@ -172,7 +176,7 @@ def fused_page_attention_pallas(
         tok_k, tok_sk, tok_v, tok_sv, cold_k, cold_v, pscale_k, pscale_v,
         sym_k, ofs_k, stored_k, sym_v, ofs_v, stored_v, vm, ol, cum, *,
         n_steps: int, num_heads: int, h_full: int | None = None,
-        bits: int = 8, softcap: float = 0.0, interpret: bool = True):
+        bits: int = 8, softcap: float = 0.0, interpret: bool | None = None):
     """Fused paged attention over a job batch.
 
     Args:
@@ -194,13 +198,19 @@ def fused_page_attention_pallas(
       vm/ol/cum: stacked table arrays [T, 17] / [T, 16] / [T, 17].
       h_full:    total kv heads a PACKED page decodes to (defaults to the
                  dense planes' head count; differs only under head-TP).
+      interpret: None takes the platform's choice (``ops._default_backend``).
 
     Returns (acc f32[J, Hq, dh], m f32[J, Hq], l f32[J, Hq]) — the
     *unnormalized* online-softmax state; callers merge the current token
     and divide (see ``modules.paged_attention_step``).
+
+    Per-page and per-job scalars (lifecycle state, positions, tables) ride
+    in SMEM blocks; every VMEM block's trailing two dims are whole array
+    dims, as Mosaic requires, so small per-page vectors get a unit axis.
     """
     j, hq, dh = q.shape
     p_slots = page_idx.shape[1]
+    n_pool = tok_k.shape[0]
     ps = tok_k.shape[1]
     hkv = tok_k.shape[2]
     g = hq // hkv
@@ -222,67 +232,82 @@ def fused_page_attention_pallas(
                             lambda i, p, idx, tid:
                             (idx[i * p_slots + p],) + (0,) * len(shape))
 
-    def ktab_spec(n):
-        return pl.BlockSpec((1, n),
-                            lambda i, p, idx, tid: (tid[i * p_slots + p], 0))
-
-    def vtab_spec(n):
+    def tab_spec(n, kind):
         return pl.BlockSpec(
-            (1, n), lambda i, p, idx, tid: (tid[i * p_slots + p] + 1, 0))
+            (1, 1, n), lambda i, p, idx, tid: (tid[i * p_slots + p] + kind,
+                                               0, 0),
+            memory_space=pltpu.SMEM)
 
+    job_spec = pl.BlockSpec((1, 1, 3), lambda i, p, idx, tid: (i, 0, 0),
+                            memory_space=pltpu.SMEM)
+    meta_spec = pl.BlockSpec((1, 1, 2),
+                             lambda i, p, idx, tid: (i * p_slots + p, 0, 0),
+                             memory_space=pltpu.SMEM)
+    scratch = [
+        pltpu.VMEM((ps, hkv, dh), F32),    # k tile
+        pltpu.VMEM((ps, hkv, dh), F32),    # v tile
+        pltpu.VMEM((hkv, g, dh), F32),     # acc
+        pltpu.VMEM((hkv, g), F32),         # m
+        pltpu.VMEM((hkv, g), F32),         # l
+        pltpu.VMEM((n_steps, s), I32),     # decoded PACKED page
+    ]
+    if hkv != h_full:
+        scratch.append(pltpu.VMEM((ps, h_full, dh), F32))   # all heads
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(j, p_slots),
         in_specs=[
-            pl.BlockSpec((1, hq, dh), lambda i, p, idx, tid: (i, 0, 0)),
-            pl.BlockSpec((1, 3), lambda i, p, idx, tid: (i, 0)),
-            pl.BlockSpec((1, 1, 2), lambda i, p, idx, tid: (i, p, 0)),
+            pl.BlockSpec((1, hkv, g, dh),
+                         lambda i, p, idx, tid: (i, 0, 0, 0)),
+            job_spec,
+            meta_spec,
             page_spec((ps, hkv, dh)),          # tok_k
             page_spec((ps, hkv)),              # tok_sk
             page_spec((ps, hkv, dh)),          # tok_v
             page_spec((ps, hkv)),              # tok_sv
             page_spec((ps, hkv, dh)),          # cold_k
             page_spec((ps, hkv, dh)),          # cold_v
-            page_spec((hkv,)),                 # pscale_k
-            page_spec((hkv,)),                 # pscale_v
+            page_spec((hkv, 1)),               # pscale_k
+            page_spec((hkv, 1)),               # pscale_v
             page_spec((ws, s)),                # sym_k
             page_spec((wo, s)),                # ofs_k
-            page_spec((s,)),                   # stored_k
+            page_spec((1, s)),                 # stored_k
             page_spec((ws, s)),                # sym_v
             page_spec((wo, s)),                # ofs_v
-            page_spec((s,)),                   # stored_v
-            ktab_spec(17), ktab_spec(16), ktab_spec(17),
-            vtab_spec(17), vtab_spec(16), vtab_spec(17),
+            page_spec((1, s)),                 # stored_v
+            tab_spec(17, 0), tab_spec(16, 0), tab_spec(17, 0),
+            tab_spec(17, 1), tab_spec(16, 1), tab_spec(17, 1),
         ],
         out_specs=[
-            pl.BlockSpec((1, hq, dh), lambda i, p, idx, tid: (i, 0, 0)),
-            pl.BlockSpec((1, hq), lambda i, p, idx, tid: (i, 0)),
-            pl.BlockSpec((1, hq), lambda i, p, idx, tid: (i, 0)),
+            pl.BlockSpec((1, hkv, g, dh),
+                         lambda i, p, idx, tid: (i, 0, 0, 0)),
+            pl.BlockSpec((1, hkv, g), lambda i, p, idx, tid: (i, 0, 0)),
+            pl.BlockSpec((1, hkv, g), lambda i, p, idx, tid: (i, 0, 0)),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((ps, hkv, dh), F32),    # k tile
-            pltpu.VMEM((ps, hkv, dh), F32),    # v tile
-            pltpu.VMEM((hkv, g, dh), F32),     # acc
-            pltpu.VMEM((hkv, g), F32),         # m
-            pltpu.VMEM((hkv, g), F32),         # l
-        ],
+        scratch_shapes=scratch,
     )
-    return pl.pallas_call(
+    vm3, ol3, cum3 = (t.astype(I32)[:, None, :] for t in (vm, ol, cum))
+    acc, m, l = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((j, hq, dh), F32),
-            jax.ShapeDtypeStruct((j, hq), F32),
-            jax.ShapeDtypeStruct((j, hq), F32),
+            jax.ShapeDtypeStruct((j, hkv, g, dh), F32),
+            jax.ShapeDtypeStruct((j, hkv, g), F32),
+            jax.ShapeDtypeStruct((j, hkv, g), F32),
         ],
-        interpret=interpret,
-    )(idx_flat, tid_flat, q.astype(F32), jobmeta.astype(I32),
-      meta.astype(I32), tok_k, tok_sk.astype(F32), tok_v,
-      tok_sv.astype(F32), cold_k, cold_v, pscale_k.astype(F32),
-      pscale_v.astype(F32), sym_k.astype(U32), ofs_k.astype(U32),
-      stored_k.astype(I32), sym_v.astype(U32), ofs_v.astype(U32),
-      stored_v.astype(I32), vm.astype(I32), ol.astype(I32), cum.astype(I32),
-      vm.astype(I32), ol.astype(I32), cum.astype(I32))
+        interpret=resolve_interpret(interpret),
+        name="fused_page_attention",
+    )(idx_flat, tid_flat, q.astype(F32).reshape(j, hkv, g, dh),
+      jobmeta.astype(I32)[:, None, :],
+      meta.astype(I32).reshape(j * p_slots, 1, 2), tok_k,
+      tok_sk.astype(F32), tok_v, tok_sv.astype(F32), cold_k, cold_v,
+      pscale_k.astype(F32).reshape(n_pool, hkv, 1),
+      pscale_v.astype(F32).reshape(n_pool, hkv, 1),
+      sym_k.astype(U32), ofs_k.astype(U32),
+      stored_k.astype(I32)[:, None, :], sym_v.astype(U32),
+      ofs_v.astype(U32), stored_v.astype(I32)[:, None, :],
+      vm3, ol3, cum3, vm3, ol3, cum3)
+    return acc.reshape(j, hq, dh), m.reshape(j, hq), l.reshape(j, hq)
 
 
 # apack: allow-jit-cache(softcap is one value per served model config --

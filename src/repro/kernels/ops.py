@@ -25,7 +25,16 @@ U32 = jnp.uint32
 
 
 def _default_backend() -> str:
-    return "pallas_interpret" if jax.default_backend() == "cpu" else "pallas"
+    """The one place the kernel backend is chosen: Pallas compiled for the
+    TPU, the Pallas interpreter on the CPU (tests and rehearsals).  Any
+    other platform is an error, never a silent interpreter run."""
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return "pallas"
+    if platform == "cpu":
+        return "pallas_interpret"
+    raise RuntimeError(f"APack kernels run on TPU or (interpreted) CPU, "
+                       f"not on {platform!r}")
 
 
 @jax.tree_util.register_pytree_node_class

@@ -42,7 +42,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import ref as _ref
-from .apack_decode import decode_block
+from .apack_decode import decode_block, resolve_interpret, table_scalars
 
 I32 = jnp.int32
 U32 = jnp.uint32
@@ -151,10 +151,9 @@ def _gather_decode_kernel(idx_ref, tid_ref, sym_ref, ofs_ref, stored_ref,
                           vmin_ref, ol_ref, cum_ref, out_ref, *, n_steps: int,
                           bits: int):
     del idx_ref, tid_ref            # consumed by the BlockSpec index_maps
-    out_ref[0] = decode_block(
-        sym_ref[0].astype(U32), ofs_ref[0].astype(U32), stored_ref[0] != 0,
-        vmin_ref[0], ol_ref[0], cum_ref[0],
-        n_steps=n_steps, bits=bits)
+    decode_block(sym_ref.at[0], ofs_ref.at[0], stored_ref[0] != 0,
+                 table_scalars(vmin_ref, ol_ref, cum_ref, 0, 0),
+                 out_ref.at[0], n_steps=n_steps, bits=bits)
 
 
 @functools.partial(jax.jit,
@@ -162,7 +161,7 @@ def _gather_decode_kernel(idx_ref, tid_ref, sym_ref, ofs_ref, stored_ref,
 def gather_decode_pallas(sym: jax.Array, ofs: jax.Array, stored: jax.Array,
                          page_idx: jax.Array, v_min: jax.Array,
                          ol: jax.Array, cum: jax.Array, *, n_steps: int,
-                         bits: int = 8, interpret: bool = True,
+                         bits: int = 8, interpret: bool | None = None,
                          table_idx: jax.Array | None = None) -> jax.Array:
     """Decode pages ``page_idx`` out of a pooled compressed-plane stack.
 
@@ -178,6 +177,7 @@ def gather_decode_pallas(sym: jax.Array, ofs: jax.Array, stored: jax.Array,
       table_idx: i32[G] table-stack row for each gathered page (None with
                 1-D tables: every page uses the single table).
       n_steps:  values per stream (E).
+      interpret: None takes the platform's choice (``ops._default_backend``).
 
     Returns: i32[G, S, n_steps] decoded unsigned values, gather order.
     """
@@ -188,27 +188,31 @@ def gather_decode_pallas(sym: jax.Array, ofs: jax.Array, stored: jax.Array,
                                                 table_idx)
     kernel = functools.partial(_gather_decode_kernel, n_steps=n_steps,
                                bits=bits)
+
+    def tab_spec(n):
+        return pl.BlockSpec((1, 1, n), lambda i, idx, tid: (tid[i], 0, 0),
+                            memory_space=pltpu.SMEM)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(g,),
         in_specs=[
             pl.BlockSpec((1, ws, s), lambda i, idx, tid: (idx[i], 0, 0)),
             pl.BlockSpec((1, wo, s), lambda i, idx, tid: (idx[i], 0, 0)),
-            pl.BlockSpec((1, s), lambda i, idx, tid: (idx[i], 0)),
-            pl.BlockSpec((1, 17), lambda i, idx, tid: (tid[i], 0)),
-            pl.BlockSpec((1, 16), lambda i, idx, tid: (tid[i], 0)),
-            pl.BlockSpec((1, 17), lambda i, idx, tid: (tid[i], 0)),
+            pl.BlockSpec((1, 1, s), lambda i, idx, tid: (idx[i], 0, 0)),
+            tab_spec(17), tab_spec(16), tab_spec(17),
         ],
-        out_specs=pl.BlockSpec((1, s, n_steps), lambda i, idx, tid: (i, 0, 0)),
+        out_specs=pl.BlockSpec((1, n_steps, s), lambda i, idx, tid: (i, 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((g, s, n_steps), I32),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((g, n_steps, s), I32),
+        interpret=resolve_interpret(interpret),
     )(page_idx.astype(I32), table_idx.astype(I32), sym.astype(U32),
-      ofs.astype(U32), stored.astype(I32), v_min.astype(I32), ol.astype(I32),
-      cum.astype(I32))
+      ofs.astype(U32), stored.astype(I32)[:, None, :],
+      *(t.astype(I32)[:, None, :] for t in (v_min, ol, cum)))
+    return out.transpose(0, 2, 1)
 
 
 @functools.partial(jax.jit, static_argnames=("n_steps", "bits"))

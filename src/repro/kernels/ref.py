@@ -8,8 +8,9 @@ finite-precision coder as ``core/ac_golden.py`` (16-bit HI/LO windows,
 10-bit counts, WNC renormalization) and is asserted bit-exact against it.
 
 The Pallas kernels in ``apack_decode.py`` / ``apack_encode.py`` mirror this
-file operation-for-operation; this module doubles as the production software
-path on CPU.
+file's arithmetic operation-for-operation (the decoder kernel has its own
+word reader, see ``apack_decode``); this module doubles as the production
+software path on CPU.
 """
 from __future__ import annotations
 
@@ -101,11 +102,13 @@ def renorm_counts(low: jax.Array, high: jax.Array):
     return m, u, low_f, high_f
 
 
-def decode_renorm(low, high, code, spos, low2, high2, sym_plane, stored):
+def decode_renorm(low, high, code, spos, low2, high2, read, stored):
     """Decoder side of the multi-bit renormalization: renormalize the
     post-update interval ``low2``/``high2``, consume all m+u stream bits in
     one read, and update the CODE register in closed form.  Shared by
-    ``decode`` and the Pallas ``decode_block``.
+    ``decode`` and the Pallas ``decode_block``, which differ only in how
+    they fetch stream words: ``read(pos, k)`` returns the ``k`` symbol-plane
+    bits at bit position ``pos`` of each stream.
 
     ``low``/``high``/``code``/``spos`` are the pre-update values, returned
     unchanged for stored lanes.  Valid streams need at most 16 bits per
@@ -115,7 +118,7 @@ def decode_renorm(low, high, code, spos, low2, high2, sym_plane, stored):
     m, u, low3, high3 = renorm_counts(low2, high2)
     k = jnp.minimum(m + u, 16)
     u = jnp.minimum(u, k - jnp.minimum(m, k))
-    w = read_bits(sym_plane, spos, k)
+    w = read(spos, k)
     r = shr32(rev16(w), 16 - k).astype(I32)           # first-read bit = MSB
     r_m = shr32(r.astype(U32), u).astype(I32)
     ufill = (shl32(jnp.ones_like(u, U32), u) - U32(1)).astype(I32)
@@ -227,7 +230,8 @@ def decode(sym_plane: jax.Array, ofs_plane: jax.Array, stored: jax.Array,
         high2 = low + ((rng * chi) >> PCOUNT_BITS) - 1
         low2 = low + ((rng * clo) >> PCOUNT_BITS)
         low3, high3, code3, spos3 = decode_renorm(
-            low, high, code, spos, low2, high2, sym_plane, stored)
+            low, high, code, spos, low2, high2,
+            partial(read_bits, sym_plane), stored)
         return (low3, high3, code3, spos3, opos), value
 
     init = (zeros, jnp.full((S,), TOP, I32), code0, spos0, zeros)
@@ -256,13 +260,24 @@ def _flush(plane, widx, sidx, buf_lo, buf_hi, buflen):
     return plane, widx, buf_lo, buf_hi, buflen
 
 
+# Fixed-capacity planes are sized in whole (8, 128) u32 tiles: the TPU
+# stores and DMAs 8-word row groups anyway, and the Pallas decoder's word
+# window (``apack_decode``) loads the plane one 8-row group at a time.
+WORD_GROUP = 8
+
+
+def _whole_groups(words: int) -> int:
+    return -(-words // WORD_GROUP) * WORD_GROUP
+
+
 def sym_capacity_words(n_steps: int) -> int:
     # <= MAX_RENORM bits/step sustained + termination & slack
-    return (n_steps * (MAX_RENORM + 2) + MAX_PENDING + 64 + 31) // 32
+    return _whole_groups(
+        (n_steps * (MAX_RENORM + 2) + MAX_PENDING + 64 + 31) // 32)
 
 
 def ofs_capacity_words(n_steps: int, bits: int) -> int:
-    return (n_steps * bits + 63) // 32
+    return _whole_groups((n_steps * bits + 63) // 32)
 
 
 @partial(jax.jit, static_argnames=("n_steps", "bits"))

@@ -14,6 +14,7 @@ import jax
 import numpy as np
 
 from repro import configs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.serve import (DEFAULT_WEIGHT_MIN_SIZE, Request, ServeEngine,
                          compress_params, decompress_params)
@@ -99,6 +100,7 @@ def main() -> None:
                          "and DATA*MODEL visible devices (debug: "
                          "XLA_FLAGS=--xla_force_host_platform_device_count)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = (configs.get_smoke_config(args.arch) if args.smoke
            else configs.get_config(args.arch))
@@ -154,7 +156,10 @@ def main() -> None:
     t0 = time.time()
     engine.run_until_drained()
     dt = time.time() - t0
-    assert all(r.done for r in reqs)
+    failed = [(r.rid, r.error) for r in reqs
+              if not r.done or r.error is not None]
+    if failed:
+        raise SystemExit(f"requests failed: {failed}")
     print(f"{engine.stats} in {dt:.1f}s "
           f"({engine.stats['generated']/max(dt,1e-9):.1f} tok/s)")
     if args.weights:

@@ -761,10 +761,10 @@ def build_sharded_step(cfg: ModelConfig, mesh, *, backend: str | None = None,
     replicate over "model" (the APack stream layout interleaves heads);
     each model shard decodes the full page and slices its local head
     block, then an ``all_gather`` over "model" reassembles head-major
-    accumulators before the output projection — greedy tokens stay
-    bit-identical to the single-device engine because per-kv-head
-    attention has no cross-head reduction and the gather restores exact
-    head order.
+    accumulators before the output projection.  Per-kv-head attention
+    has no cross-head reduction and the gather restores exact head
+    order, so the partitioning changes no arithmetic; a TPU may still
+    round the unsharded single-device step differently (DESIGN.md §11).
 
     Returns ``step(params, planes, states, meta, tokens, pos, targets)
     -> (logits, toks, planes', states')`` where ``toks`` is the greedy
@@ -807,13 +807,12 @@ def build_sharded_step(cfg: ModelConfig, mesh, *, backend: str | None = None,
     target_specs = _paged_tree_specs(cfg, P("data"), P(None, "data"), None)
     param_specs = (P() if params is None
                    else packed_param_specs(params, n_model))
-    from jax.experimental.shard_map import shard_map
-    stepped = shard_map(
-        _body, mesh=mesh,
+    stepped = jax.shard_map(
+        _body, mesh=shd.auto_mesh(mesh),
         in_specs=(param_specs, plane_specs, state_specs, meta_specs,
                   P("data"), P("data"), target_specs),
         out_specs=(P("data"), P("data"), plane_specs, state_specs),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(stepped)
 
 

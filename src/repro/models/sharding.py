@@ -11,7 +11,43 @@ from __future__ import annotations
 from typing import Any
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+
+
+def auto_mesh(mesh):
+    """``mesh`` with every axis ``Auto``.
+
+    The rules here are GSPMD constraints and ``shard_map`` specs, which
+    need Auto axes; ``jax.make_mesh`` makes Explicit ones by default, and
+    an Explicit mesh turns every sharding built on it into a type that
+    JAX propagates (and rejects) op by op.  Every sharding and mesh this
+    repo hands to JAX goes through here, so callers may pass either."""
+    if not isinstance(mesh, Mesh) or all(
+            t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
+
+def named(mesh, spec: P) -> NamedSharding:
+    return NamedSharding(auto_mesh(mesh), spec)
+
+
+def auto_typed(tree: Any) -> Any:
+    """Inside a trace: re-type leaves placed on a mesh with Explicit axes
+    (e.g. a caller's ``NamedSharding(jax.make_mesh(...), P())``) onto the
+    same mesh with Auto axes, so they combine with the Auto-typed arrays
+    of ``named`` shardings.  Leaves already Auto pass through."""
+    def one(x):
+        s = jax.typeof(x).sharding
+        mesh = getattr(s, "mesh", None)
+        if mesh is None or not mesh.explicit_axes:
+            return x
+        auto = mesh.update_axis_types(
+            {a: AxisType.Auto for a in mesh.explicit_axes})
+        return jax.sharding.reshard(x, NamedSharding(auto, s.spec))
+
+    return jax.tree.map(one, tree)
 
 
 def fsdp_axes(mesh: Mesh) -> tuple[str, ...]:
@@ -130,7 +166,7 @@ def param_shardings(mesh: Mesh, params: Any) -> Any:
         spec = _param_spec(_path_str(path), leaf, f)
         if len(spec) < leaf.ndim:           # pad leading dims (layer stack)
             spec = P(*([None] * (leaf.ndim - len(spec))), *spec)
-        return NamedSharding(mesh, fit_spec(spec, leaf.shape, mesh))
+        return named(mesh, fit_spec(spec, leaf.shape, mesh))
 
     return jax.tree_util.tree_map_with_path(one, params)
 
@@ -151,7 +187,7 @@ def cache_shardings(mesh: Mesh, caches: Any) -> Any:
                          P(*lead, dp, "model", None, None),
                          P(*lead, dp, None, None, None)):
                 if cand == fit_spec(cand, leaf.shape, mesh):
-                    return NamedSharding(mesh, cand)
+                    return named(mesh, cand)
         if p.endswith("_scale"):
             # [(layers,) B, S, Hkv]
             lead = [None] * (nd - 3)
@@ -159,12 +195,12 @@ def cache_shardings(mesh: Mesh, caches: Any) -> Any:
                          P(*lead, dp, "model", None),
                          P(*lead, dp, None, None)):
                 if cand == fit_spec(cand, leaf.shape, mesh):
-                    return NamedSharding(mesh, cand)
+                    return named(mesh, cand)
         if nd >= 2:
             spec = P(*([None] * (nd - 2)), dp, "model")
         else:
             spec = P(*([None] * nd))
-        return NamedSharding(mesh, fit_spec(spec, leaf.shape, mesh))
+        return named(mesh, fit_spec(spec, leaf.shape, mesh))
 
     return jax.tree_util.tree_map_with_path(one, caches)
 
@@ -177,7 +213,7 @@ def batch_shardings(mesh: Mesh, batch: Any) -> Any:
             spec = P(dp, *([None] * (leaf.ndim - 1)))
         else:
             spec = P()
-        return NamedSharding(mesh, fit_spec(spec, leaf.shape, mesh))
+        return named(mesh, fit_spec(spec, leaf.shape, mesh))
 
     return jax.tree_util.tree_map_with_path(one, batch)
 
@@ -188,11 +224,11 @@ def activation_constraint(mesh: Mesh, h: jax.Array, *,
     optionally, sequence-parallel over model."""
     dp = dp_axes(mesh)
     spec = P(dp, "model", None) if seq_shard else P(dp, None, None)
-    return jax.lax.with_sharding_constraint(h, NamedSharding(mesh, spec))
+    return jax.lax.with_sharding_constraint(h, named(mesh, spec))
 
 
 def logits_sharding(mesh: Mesh) -> NamedSharding:
-    return NamedSharding(mesh, P(dp_axes(mesh), None, "model"))
+    return named(mesh, P(dp_axes(mesh), None, "model"))
 
 
 # --------------------------------------------- paged pool plane partitioning
@@ -278,7 +314,7 @@ def plane_shardings(mesh: Mesh, planes: dict) -> dict:
     ``fit_spec`` drops any axis that doesn't divide (e.g. kv-heads on an
     oversized model axis -> replicated heads; the kernel TP path is gated
     on divisibility separately)."""
-    return {k: NamedSharding(mesh, fit_spec(plane_pspec(k), v.shape, mesh))
+    return {k: named(mesh, fit_spec(plane_pspec(k), v.shape, mesh))
             for k, v in planes.items()}
 
 
@@ -291,7 +327,7 @@ _CTX: dict = {"mesh": None, "seq_shard": False, "moe_ep": False}
 
 def set_mesh_context(mesh: Mesh | None, *, seq_shard: bool = False,
                      moe_ep: bool = False) -> None:
-    _CTX["mesh"] = mesh
+    _CTX["mesh"] = auto_mesh(mesh)
     _CTX["seq_shard"] = seq_shard
     _CTX["moe_ep"] = moe_ep
 
@@ -341,4 +377,4 @@ def constrain(x: jax.Array, kind: str) -> jax.Array:
     # waste, not help.
     if x.shape[0] % _axes_size(mesh, spec[0]) != 0:
         spec = P(None, *spec[1:])
-    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+    return jax.lax.with_sharding_constraint(x, named(mesh, spec))

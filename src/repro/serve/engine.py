@@ -37,6 +37,7 @@ from repro.core import quant, tables
 from repro.kernels import fastpath, ops
 from repro.kernels.decompress_matmul import DEFAULT_WEIGHT_MIN_SIZE
 from repro.models import model as M
+from repro.models import sharding as shd
 from repro.models import modules as m
 from repro.models.config import ModelConfig
 from repro.runtime.supervisor import StragglerWatchdog, WatchdogEvent
@@ -301,7 +302,11 @@ class ServeEngine:
         # over the mesh's "data" axis (slots, state store, page planes and
         # per-shard free lists all partition with their jobs), kv-heads
         # tensor-parallel over "model" inside the fused kernel.  Greedy
-        # tokens stay bit-identical to the single-device engine.
+        # tokens are bit-identical to a one-device engine running the
+        # same sharded step at one shard's batch; against the unsharded
+        # engine at the full batch they can differ by rounding on a TPU
+        # (DESIGN.md §11).
+        mesh = shd.auto_mesh(mesh)
         self.mesh = mesh
         self._n_data = 1
         self._n_model = 1

@@ -16,22 +16,11 @@ reduces them.
 """
 from __future__ import annotations
 
-import inspect
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:                                     # newer jax: top-level API
-    _shard_map = jax.shard_map
-except AttributeError:                   # older jax: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
-# the replication-check kwarg was renamed check_rep -> check_vma
-# independently of the namespace promotion, so key on the signature
-_SHMAP_KW = ("check_vma"
-             if "check_vma" in inspect.signature(_shard_map).parameters
-             else "check_rep")
 
 F32 = jnp.float32
 BLOCK = 512
@@ -81,9 +70,9 @@ def compressed_psum_mean(grads: Any, mesh: Mesh, axes: tuple[str, ...],
             return tot, smax
 
         spec = P()
-        tot, smax = _shard_map(inner, mesh=mesh, in_specs=(spec, spec),
-                               out_specs=(spec, spec),
-                               **{_SHMAP_KW: False})(q, scale)
+        tot, smax = jax.shard_map(inner, mesh=mesh, in_specs=(spec, spec),
+                                  out_specs=(spec, spec),
+                                  check_vma=False)(q, scale)
         mean = dequantize_blockwise(tot, smax, n, g.shape) / n_dev
         return mean, new_e
 

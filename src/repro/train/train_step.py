@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import model as M
+from repro.models import sharding as shd
 from repro.models.config import ModelConfig
 from . import optimizer as opt
 
@@ -37,6 +38,7 @@ def make_train_step(cfg: ModelConfig, ocfg: opt.AdamWConfig,
     loss_fn = make_loss_fn(cfg)
 
     def step(params, opt_state, batch):
+        params, opt_state, batch = shd.auto_typed((params, opt_state, batch))
         if grad_accum == 1:
             loss, grads = jax.value_and_grad(loss_fn)(params, batch)
         else:

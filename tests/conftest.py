@@ -1,20 +1,7 @@
 """Test config.  IMPORTANT: never set xla_force_host_platform_device_count
 here — smoke tests must see 1 device; multi-device tests spawn subprocesses
 (tests/test_distributed.py)."""
-import sys
-
-import pytest
-
-try:
-    from hypothesis import HealthCheck, settings
-except ImportError:
-    # hypothesis is optional (see requirements.txt).  Install the local
-    # stub under the "hypothesis" name so @given property tests still run
-    # with a fixed set of deterministic examples.
-    import _hypothesis_stub
-    sys.modules["hypothesis"] = _hypothesis_stub
-    sys.modules["hypothesis.strategies"] = _hypothesis_stub.strategies
-    from hypothesis import HealthCheck, settings
+from hypothesis import HealthCheck, settings
 
 settings.register_profile(
     "ci", deadline=None,
