@@ -16,6 +16,7 @@ import numpy as np
 from repro import configs
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
+from repro.runtime import spans
 from repro.serve import (DEFAULT_WEIGHT_MIN_SIZE, Request, ServeEngine,
                          compress_params, decompress_params)
 
@@ -176,6 +177,8 @@ def main() -> None:
         print(f"latency ({args.scheduler} scheduler, n={lat['n']}): "
               f"queue-wait p50={lat['queue_wait_p50']*1e3:.1f}ms "
               f"p99={lat['queue_wait_p99']*1e3:.1f}ms; "
+              f"ttft p50={lat['ttft_p50']*1e3:.1f}ms "
+              f"p99={lat['ttft_p99']*1e3:.1f}ms; "
               f"e2e p50={lat['e2e_p50']*1e3:.1f}ms "
               f"p99={lat['e2e_p99']*1e3:.1f}ms")
     if engine.paged:
@@ -224,6 +227,10 @@ def main() -> None:
               f"h2d={tr['h2d_bytes']/1e3:.1f} kB "
               f"d2h={tr['d2h_bytes']/1e3:.1f} kB "
               f"({tr['h2d_calls']}/{tr['d2h_calls']} calls)")
+    print("program spans (whole process):")
+    print(f"  {'span':26s} {'count':>8s} {'total s':>10s} {'mean ms':>10s}")
+    for name, (n, secs) in sorted(spans.totals().items()):
+        print(f"  {name:26s} {n:8d} {secs:10.3f} {secs / n * 1e3:10.3f}")
     print("sample output:", reqs[0].tokens[:16])
 
 

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.paged_decode import table_row
+from repro.runtime.spans import span
 
 from . import modules as m
 from . import sharding as shd
@@ -1409,9 +1410,10 @@ class PagedKVCache:
         (``prefill_host_view`` -> ``ingest_prefill_chunk``* ->
         ``finish_prefill``) that the async engine paginates across decode
         steps, so one long prompt never stalls the batch."""
-        view = self.prefill_host_view(caches)
-        self.ingest_prefill_chunk(rid, view, 0, s, s)
-        self.finish_prefill(rid, view, s)
+        with span("kv.ingest"):
+            view = self.prefill_host_view(caches)
+            self.ingest_prefill_chunk(rid, view, 0, s, s)
+            self.finish_prefill(rid, view, s)
 
     def prefill_host_view(self, caches: dict) -> dict:
         """One batched d2h pull of a (batch-1) prefill cache into host
@@ -1419,22 +1421,23 @@ class PagedKVCache:
         layers as their field dicts.  Forcing the view blocks on the
         prefill computation, so the async engine calls this during the
         overlap window where the wait rides the in-flight decode step."""
-        view: dict = {}
-        for layer in self.attn_layers:
-            leaf, j = self._layer_cache(caches, layer)
+        with span("kv.ingest.pull"):
+            view: dict = {}
+            for layer in self.attn_layers:
+                leaf, j = self._layer_cache(caches, layer)
 
-            def one(f, leaf=leaf, j=j):
-                x = leaf[f] if j is None else leaf[f][j]
-                return np.asarray(self._fetch(x))[0]
+                def one(f, leaf=leaf, j=j):
+                    x = leaf[f] if j is None else leaf[f][j]
+                    return np.asarray(self._fetch(x))[0]
 
-            view[layer] = (one("k"), one("v"), one("k_scale"),
-                           one("v_scale"))
-        for layer in self.state_layers:
-            leaf, j = self._layer_cache(caches, layer)
-            view[layer] = {
-                f: np.asarray(self._fetch(x if j is None else x[j]))[0]
-                for f, x in leaf.items()}
-        return view
+                view[layer] = (one("k"), one("v"), one("k_scale"),
+                               one("v_scale"))
+            for layer in self.state_layers:
+                leaf, j = self._layer_cache(caches, layer)
+                view[layer] = {
+                    f: np.asarray(self._fetch(x if j is None else x[j]))[0]
+                    for f, x in leaf.items()}
+            return view
 
     def ingest_prefill_chunk(self, rid: int, view: dict, t0: int, t1: int,
                              s: int) -> None:
@@ -1476,56 +1479,59 @@ class PagedKVCache:
     def _seal(self, layer: int, pid: int) -> None:
         """Full HOT page -> COLD: re-quantize to one scale per (page, head)
         — scale amortization — then calibrate or pack."""
-        from repro.core import quant, tables as ctables
-        from repro.core.tables import TABLE_OVERHEAD_BITS
-        pool = self.pool
-        q2 = np.zeros((2, self.page_size, pool.kv_heads, pool.head_dim),
-                      np.int8)
-        scale2 = np.zeros((2, pool.kv_heads), np.float32)
-        for kind in (0, 1):
-            f = (pool.tok_q[kind, pid].astype(np.float32)
-                 * pool.tok_scale[kind, pid][..., None])
-            sc = np.maximum(np.abs(f).max(axis=(0, 2)), 1e-8) / 127.0
-            q2[kind] = np.clip(np.round(f / sc[None, :, None]),
-                               -127, 127).astype(np.int8)
-            scale2[kind] = sc
-        pool.seal(pid, q2, scale2)
-        self._cold[layer].add(pid)
-        self._mark_dirty(pid)
-        if self.tables[layer][0] is not None:
-            # drift monitor: every post-calibration sealed page feeds the
-            # layer's symbol-frequency sketch — the same 256-bin histogram
-            # calibration used, accumulated here where the page payload is
-            # already in host memory (zero extra transfers; in fused mode
-            # this rides the amortized seal pull)
+        with span("kv.seal"):
+            from repro.core import quant, tables as ctables
+            from repro.core.tables import TABLE_OVERHEAD_BITS
+            pool = self.pool
+            q2 = np.zeros((2, self.page_size, pool.kv_heads, pool.head_dim),
+                          np.int8)
+            scale2 = np.zeros((2, pool.kv_heads), np.float32)
+            with span("kv.seal.requantize"):
+                for kind in (0, 1):
+                    f = (pool.tok_q[kind, pid].astype(np.float32)
+                         * pool.tok_scale[kind, pid][..., None])
+                    sc = np.maximum(np.abs(f).max(axis=(0, 2)), 1e-8) / 127.0
+                    q2[kind] = np.clip(np.round(f / sc[None, :, None]),
+                                       -127, 127).astype(np.int8)
+                    scale2[kind] = sc
+            pool.seal(pid, q2, scale2)
+            self._cold[layer].add(pid)
+            self._mark_dirty(pid)
+            if self.tables[layer][0] is not None:
+                # drift monitor: every post-calibration sealed page feeds
+                # the layer's symbol-frequency sketch — the same 256-bin
+                # histogram calibration used, accumulated here where the
+                # page payload is already in host memory (zero extra
+                # transfers; in fused mode this rides the amortized seal
+                # pull)
+                for kind in (0, 1):
+                    u = quant.to_unsigned(q2[kind]).reshape(-1)
+                    self.drift_hists[layer, kind] += np.bincount(u,
+                                                                 minlength=256)
+                self.drift_pages[layer] += 1
+                self._drift_changed.add(layer)
+                self._pack(layer, pid)
+                return
             for kind in (0, 1):
                 u = quant.to_unsigned(q2[kind]).reshape(-1)
-                self.drift_hists[layer, kind] += np.bincount(u,
-                                                             minlength=256)
-            self.drift_pages[layer] += 1
-            self._drift_changed.add(layer)
-            self._pack(layer, pid)
-            return
-        for kind in (0, 1):
-            u = quant.to_unsigned(q2[kind]).reshape(-1)
-            self.hists[layer, kind] += np.bincount(u, minlength=256)
-            self.hist_pages[layer, kind] += 1
-        if int(self.hist_pages[layer, 0]) >= self.calib_pages:
-            for kind in (0, 1):
-                self.tables[layer][kind] = ctables.find_table(
-                    self.hists[layer, kind], bits=8, is_activation=True)
-                self.calib_bits[layer, kind] = \
-                    ctables.expected_bits_per_value(self.hists[layer, kind],
-                                                    self.tables[layer][kind])
-            # a late-calibrating layer installs into the *current*
-            # generation (its rows in older generations stay zero and are
-            # never referenced: no page of this layer is PACKED yet)
-            self.table_gen[layer] = self.generation
-            self._table_stack = None
-            self._tables_dirty = True
-            self.traffic["kv_table_bytes"] += 2 * TABLE_OVERHEAD_BITS // 8
-            for cold_pid in sorted(self._cold[layer]):
-                self._pack(layer, cold_pid)
+                self.hists[layer, kind] += np.bincount(u, minlength=256)
+                self.hist_pages[layer, kind] += 1
+            if int(self.hist_pages[layer, 0]) >= self.calib_pages:
+                for kind in (0, 1):
+                    self.tables[layer][kind] = ctables.find_table(
+                        self.hists[layer, kind], bits=8, is_activation=True)
+                    self.calib_bits[layer, kind] = \
+                        ctables.expected_bits_per_value(
+                            self.hists[layer, kind], self.tables[layer][kind])
+                # a late-calibrating layer installs into the *current*
+                # generation (its rows in older generations stay zero and are
+                # never referenced: no page of this layer is PACKED yet)
+                self.table_gen[layer] = self.generation
+                self._table_stack = None
+                self._tables_dirty = True
+                self.traffic["kv_table_bytes"] += 2 * TABLE_OVERHEAD_BITS // 8
+                for cold_pid in sorted(self._cold[layer]):
+                    self._pack(layer, cold_pid)
 
     def _pack(self, layer: int, pid: int) -> None:
         """COLD -> PACKED: APack-encode both kinds with the layer's
@@ -1533,16 +1539,17 @@ class PagedKVCache:
         from repro.core import quant
         from repro.kernels import ref as _codec
         pool = self.pool
-        outs = []
-        for kind in (0, 1):
-            vals = quant.to_unsigned(pool.cold_q[kind, pid]).reshape(
-                pool.n_streams, pool.elems_per_stream)
-            ta = _codec.TableArrays.from_table(self.tables[layer][kind])
-            planes = _codec.encode(jnp.asarray(vals.astype(np.int32)), ta,
-                                   pool.elems_per_stream, 8)
-            # apack: allow-transfer(page-seal event: encoding a sealed COLD
-            # page is host work off the step critical path)
-            outs.append(tuple(np.asarray(p) for p in planes))
+        with span("kv.seal.encode"):
+            outs = []
+            for kind in (0, 1):
+                vals = quant.to_unsigned(pool.cold_q[kind, pid]).reshape(
+                    pool.n_streams, pool.elems_per_stream)
+                ta = _codec.TableArrays.from_table(self.tables[layer][kind])
+                planes = _codec.encode(jnp.asarray(vals.astype(np.int32)), ta,
+                                       pool.elems_per_stream, 8)
+                # apack: allow-transfer(page-seal event: encoding a sealed COLD
+                # page is host work off the step critical path)
+                outs.append(tuple(np.asarray(p) for p in planes))
         pool.pack(pid, tuple(np.stack([o[i] for o in outs])
                              for i in range(5)))
         self._cold[layer].discard(pid)
@@ -1558,13 +1565,14 @@ class PagedKVCache:
     def _plane_crc(self, pid: int) -> int:
         """Integrity checksum of a PACKED page's compressed planes + page
         scales — the page metadata companion of ``page_gen``."""
-        pool = self.pool
-        return m.payload_crc({"sym": pool.sym[:, pid],
-                              "ofs": pool.ofs[:, pid],
-                              "sym_bits": pool.sym_bits[:, pid],
-                              "ofs_bits": pool.ofs_bits[:, pid],
-                              "stored": pool.stored[:, pid],
-                              "page_scale": pool.page_scale[:, pid]})
+        with span("kv.seal.crc"):
+            pool = self.pool
+            return m.payload_crc({"sym": pool.sym[:, pid],
+                                  "ofs": pool.ofs[:, pid],
+                                  "sym_bits": pool.sym_bits[:, pid],
+                                  "ofs_bits": pool.ofs_bits[:, pid],
+                                  "stored": pool.stored[:, pid],
+                                  "page_scale": pool.page_scale[:, pid]})
 
     @property
     def n_table_rows(self) -> int:
@@ -2168,17 +2176,19 @@ class PagedKVCache:
     def _flush_device(self) -> None:
         if self.dev is None:
             return
-        changed = self._tables_dirty or bool(self._dirty)
-        if self._tables_dirty:
-            self._sync_tables_to_device()
-        if self._dirty:
-            self.sync_pages_to_device(sorted(self._dirty))
-            self._dirty.clear()
-        if changed:
-            # mesh mode: eager event scatters can degrade plane layouts;
-            # repin here (no-op without a mesh) so the next sharded step
-            # sees canonical partitioning instead of an implicit reshard
-            self.dev.repin()
+        with span("kv.flush"):
+            changed = self._tables_dirty or bool(self._dirty)
+            if self._tables_dirty:
+                self._sync_tables_to_device()
+            if self._dirty:
+                self.sync_pages_to_device(sorted(self._dirty))
+                self._dirty.clear()
+            if changed:
+                # mesh mode: eager event scatters can degrade plane
+                # layouts; repin here (no-op without a mesh) so the next
+                # sharded step sees canonical partitioning instead of an
+                # implicit reshard
+                self.dev.repin()
 
     def sync_request_to_device(self, rid: int) -> None:
         """Admission-time push: every page of a freshly-ingested request
@@ -2222,33 +2232,34 @@ class PagedKVCache:
         Returns a pytree shaped like ``decode_step_paged``'s new-cache
         (``None`` at recurrent-kind positions); idle slots carry the
         out-of-range page sentinel, dropped by the scatter."""
-        b = len(slot_rids)
-        sentinel = self.pool.num_pages
-        per_layer = {layer: (np.full(b, sentinel, np.int32),
-                             np.zeros(b, np.int32))
-                     for layer in self.attn_layers}
-        for slot, rid in enumerate(slot_rids):
-            if rid is None:
-                continue
-            t = self.seq_len[rid]
-            for layer in self.attn_layers:
-                per_layer[layer][0][slot] = self._claim_page(rid, layer, t)
-                per_layer[layer][1][slot] = t % self.page_size
-        prefix = [(self._put(per_layer[i][0]), self._put(per_layer[i][1]))
-                  if kind in ATTN_KINDS else None
-                  for i, kind in enumerate(self.cfg.prefix_pattern)]
-        blocks = []
-        for c, kind in enumerate(self.cfg.cycle):
-            if kind not in ATTN_KINDS:
-                blocks.append(None)
-                continue
-            layers = [self.n_prefix + j * self.n_cycle + c
-                      for j in range(self.n_stack)]
-            blocks.append((self._put(np.stack([per_layer[l][0]
-                                               for l in layers])),
-                           self._put(np.stack([per_layer[l][1]
-                                               for l in layers]))))
-        return {"prefix": prefix, "blocks": tuple(blocks)}
+        with span("kv.claim_append"):
+            b = len(slot_rids)
+            sentinel = self.pool.num_pages
+            per_layer = {layer: (np.full(b, sentinel, np.int32),
+                                 np.zeros(b, np.int32))
+                         for layer in self.attn_layers}
+            for slot, rid in enumerate(slot_rids):
+                if rid is None:
+                    continue
+                t = self.seq_len[rid]
+                for layer in self.attn_layers:
+                    per_layer[layer][0][slot] = self._claim_page(rid, layer, t)
+                    per_layer[layer][1][slot] = t % self.page_size
+            prefix = [(self._put(per_layer[i][0]), self._put(per_layer[i][1]))
+                      if kind in ATTN_KINDS else None
+                      for i, kind in enumerate(self.cfg.prefix_pattern)]
+            blocks = []
+            for c, kind in enumerate(self.cfg.cycle):
+                if kind not in ATTN_KINDS:
+                    blocks.append(None)
+                    continue
+                layers = [self.n_prefix + j * self.n_cycle + c
+                          for j in range(self.n_stack)]
+                blocks.append((self._put(np.stack([per_layer[l][0]
+                                                   for l in layers])),
+                               self._put(np.stack([per_layer[l][1]
+                                                   for l in layers]))))
+            return {"prefix": prefix, "blocks": tuple(blocks)}
 
     def note_appended(self, slot_rids: list) -> None:
         """Metadata half of the on-device append (fused-path analogue of
@@ -2257,27 +2268,31 @@ class PagedKVCache:
         the only steady-state d2h, amortized over ``page_size`` steps),
         evict rolled-out pages, and push freshly sealed/packed planes
         back to the device."""
-        for slot, rid in enumerate(slot_rids):
-            if rid is None:
-                continue
-            for layer in self.attn_layers:
-                pid = self.page_tables[rid][layer][-1]
-                self.pool.note_device_write(pid)
-                if int(self.pool.fill[pid]) == self.page_size:
-                    self._seal_from_device(layer, pid)
-            self.seq_len[rid] += 1
-            self.evict_rolled(rid)
-        self._flush_device()
+        with span("kv.note_appended"):
+            for slot, rid in enumerate(slot_rids):
+                if rid is None:
+                    continue
+                for layer in self.attn_layers:
+                    pid = self.page_tables[rid][layer][-1]
+                    self.pool.note_device_write(pid)
+                    if int(self.pool.fill[pid]) == self.page_size:
+                        self._seal_from_device(layer, pid, rid)
+                self.seq_len[rid] += 1
+                self.evict_rolled(rid)
+            self._flush_device()
 
-    def _seal_from_device(self, layer: int, pid: int) -> None:
+    def _seal_from_device(self, layer: int, pid: int, rid: int) -> None:
         d = self.dev.planes
         if self.dev.mesh is None:
             # plain eager gather: compiles in microseconds per pid and the
             # single-device executables are trivial, so no jit is worth a
             # multi-second compile landing mid-serve (it would poison the
             # straggler watchdog's step-time baseline)
-            kq, vq, ks, vs = self._fetch((d["tok_k"][pid], d["tok_v"][pid],
-                                          d["tok_sk"][pid], d["tok_sv"][pid]))
+            with span("kv.seal.pull", rid=rid):
+                kq, vq, ks, vs = self._fetch((d["tok_k"][pid],
+                                              d["tok_v"][pid],
+                                              d["tok_sk"][pid],
+                                              d["tok_sv"][pid]))
         else:
             # on a sharded plane the page index must be a *traced* operand:
             # a static python index bakes the pid into the jaxpr, and every
@@ -2290,9 +2305,10 @@ class PagedKVCache:
             if self._page_pull is None:
                 self._page_pull = jax.jit(lambda tk, tv, sk, sv, i: (
                     tk[i], tv[i], sk[i], sv[i]))
-            kq, vq, ks, vs = self._fetch(self._page_pull(
-                d["tok_k"], d["tok_v"], d["tok_sk"], d["tok_sv"],
-                jnp.asarray(pid, jnp.int32)))
+            with span("kv.seal.pull", rid=rid):
+                kq, vq, ks, vs = self._fetch(self._page_pull(
+                    d["tok_k"], d["tok_v"], d["tok_sk"], d["tok_sv"],
+                    jnp.asarray(pid, jnp.int32)))
         self.pool.tok_q[0, pid] = kq
         self.pool.tok_q[1, pid] = vq
         self.pool.tok_scale[0, pid] = ks
@@ -2364,55 +2380,56 @@ class PagedKVCache:
         per page slot).  Also accrues the read-traffic counters the
         materialize path would have charged (same pages are read, just
         decoded at point of use)."""
-        b = len(slot_rids)
-        pmax = self.meta_pages(max_len, slot_rids)
-        ps = self.page_size
-        per_layer = {}
-        for layer in self.attn_layers:
-            per_layer[layer] = {
-                "pid": np.zeros((b, pmax), np.int32),
-                "tid": np.full((b, pmax), 2 * layer, np.int32),
-                "state": np.zeros((b, pmax), np.int32),     # FREE: masked
-                "t0": np.zeros((b, pmax), np.int32),
-                "qw": np.zeros((b, 2), np.int32),
-            }
-        for slot, rid in enumerate(slot_rids):
-            if rid is None:
-                continue
-            qpos = self.seq_len[rid]
+        with span("kv.step_meta"):
+            b = len(slot_rids)
+            pmax = self.meta_pages(max_len, slot_rids)
+            ps = self.page_size
+            per_layer = {}
             for layer in self.attn_layers:
-                kind = self.layer_kinds[layer]
-                d = per_layer[layer]
-                base = self.page_base[rid][layer]
-                for k_, pid in enumerate(self.page_tables[rid][layer]):
-                    d["pid"][slot, k_] = pid
-                    # K-row of the (generation, layer, kind) table id the
-                    # page was coded under (V row = +1 in-kernel); pages
-                    # from different refresh generations coexist per step
-                    d["tid"][slot, k_] = self._row(
-                        self._checked_gen(pid, rid, layer), layer, 0)
-                    d["state"][slot, k_] = int(self.pool.state[pid])
-                    d["t0"][slot, k_] = (base + k_) * ps
-                d["qw"][slot] = (qpos, self._ring(max_len)
-                                 if kind == "local" else 0)
-        self._accrue_read_traffic(slot_rids, max_len)
+                per_layer[layer] = {
+                    "pid": np.zeros((b, pmax), np.int32),
+                    "tid": np.full((b, pmax), 2 * layer, np.int32),
+                    "state": np.zeros((b, pmax), np.int32),     # FREE: masked
+                    "t0": np.zeros((b, pmax), np.int32),
+                    "qw": np.zeros((b, 2), np.int32),
+                }
+            for slot, rid in enumerate(slot_rids):
+                if rid is None:
+                    continue
+                qpos = self.seq_len[rid]
+                for layer in self.attn_layers:
+                    kind = self.layer_kinds[layer]
+                    d = per_layer[layer]
+                    base = self.page_base[rid][layer]
+                    for k_, pid in enumerate(self.page_tables[rid][layer]):
+                        d["pid"][slot, k_] = pid
+                        # K-row of the (generation, layer, kind) table id the
+                        # page was coded under (V row = +1 in-kernel); pages
+                        # from different refresh generations coexist per step
+                        d["tid"][slot, k_] = self._row(
+                            self._checked_gen(pid, rid, layer), layer, 0)
+                        d["state"][slot, k_] = int(self.pool.state[pid])
+                        d["t0"][slot, k_] = (base + k_) * ps
+                    d["qw"][slot] = (qpos, self._ring(max_len)
+                                     if kind == "local" else 0)
+            self._accrue_read_traffic(slot_rids, max_len)
 
-        def pack(layer_arrs):
-            return {k: self._put(v) for k, v in layer_arrs.items()}
+            def pack(layer_arrs):
+                return {k: self._put(v) for k, v in layer_arrs.items()}
 
-        prefix = [pack(per_layer[i]) if kind in ATTN_KINDS else {}
-                  for i, kind in enumerate(self.cfg.prefix_pattern)]
-        blocks = []
-        for c, kind in enumerate(self.cfg.cycle):
-            if kind not in ATTN_KINDS:
-                blocks.append({})
-                continue
-            layers = [self.n_prefix + j * self.n_cycle + c
-                      for j in range(self.n_stack)]
-            blocks.append({k: self._put(np.stack([per_layer[l][k]
-                                                  for l in layers]))
-                           for k in per_layer[layers[0]]})
-        return {"prefix": prefix, "blocks": tuple(blocks)}
+            prefix = [pack(per_layer[i]) if kind in ATTN_KINDS else {}
+                      for i, kind in enumerate(self.cfg.prefix_pattern)]
+            blocks = []
+            for c, kind in enumerate(self.cfg.cycle):
+                if kind not in ATTN_KINDS:
+                    blocks.append({})
+                    continue
+                layers = [self.n_prefix + j * self.n_cycle + c
+                          for j in range(self.n_stack)]
+                blocks.append({k: self._put(np.stack([per_layer[l][k]
+                                                      for l in layers]))
+                               for k in per_layer[layers[0]]})
+            return {"prefix": prefix, "blocks": tuple(blocks)}
 
     def _accrue_read_traffic(self, slot_rids: list, max_len: int) -> None:
         """Charge the per-step KV read traffic (shared by materialize and

@@ -1,2 +1,3 @@
+from . import spans
 from .supervisor import (StragglerWatchdog, Supervisor, SupervisorConfig,
                          WatchdogEvent)

@@ -37,16 +37,16 @@ class WatchdogEvent:
     (``consecutive`` flags reached patience — the caller should act:
     supervisor raises, engine preempts-with-spill).
 
-    ``phases`` (optional): per-phase wall-time breakdown of the observed
-    step.  The async serve engine reports its overlapped host work /
-    collect / dispatch split here, so a hung event attributes the stall
-    (host-side seal/re-pack/prefill vs the device step itself) instead
-    of reporting one opaque duration."""
+    The event carries the step's duration only.  Where the serve engine
+    spent a flagged step is in its program spans
+    (``repro.runtime.spans``): ``recorded(t - dt, t)`` over the step holds
+    ``engine.step`` and the phases nested in it (``engine.overlap_host``,
+    ``engine.collect``, ``engine.schedule_dispatch`` on the async
+    scheduler; admission, seal and dispatch spans on both)."""
     kind: str
     dt: float
     ema: float
     consecutive: int
-    phases: dict | None = None
 
 
 class StragglerWatchdog:
@@ -72,8 +72,7 @@ class StragglerWatchdog:
         self.events = 0                      # consecutive flagged steps
         self.event_log: list[WatchdogEvent] = []
 
-    def observe(self, dt: float,
-                phases: dict | None = None) -> WatchdogEvent | None:
+    def observe(self, dt: float) -> WatchdogEvent | None:
         ev = None
         if len(self.step_times) >= self.window:
             ema = float(np.median(self.step_times[-self.window:]))
@@ -82,7 +81,7 @@ class StragglerWatchdog:
                 kind = "hung" if self.events >= self.patience \
                     else "straggler"
                 ev = WatchdogEvent(kind=kind, dt=dt, ema=ema,
-                                   consecutive=self.events, phases=phases)
+                                   consecutive=self.events)
             else:
                 self.events = 0
         self.step_times.append(dt)

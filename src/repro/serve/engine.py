@@ -40,6 +40,7 @@ from repro.models import model as M
 from repro.models import sharding as shd
 from repro.models import modules as m
 from repro.models.config import ModelConfig
+from repro.runtime.spans import span
 from repro.runtime.supervisor import StragglerWatchdog, WatchdogEvent
 
 _log = logging.getLogger("repro.serve")
@@ -86,6 +87,7 @@ class Request:
     # step loop's perf_counter and can report negative latencies.
     t_submit: float = 0.0
     t_admit: float = 0.0                # prefill dispatch (queue-wait end)
+    t_first: float = 0.0                # first token appended
     t_done: float = 0.0
     # SLO: steps this request may hold a decode slot while others queue
     # (None: engine-level slot_deadline_steps, or no deadline at all)
@@ -260,9 +262,7 @@ class ServeEngine:
                       "spilled_requests": 0, "admission_retries": 0,
                       "pressure_preempted": 0, "deadline_preempted": 0,
                       "watchdog_preempted": 0, "prefill_chunks": 0,
-                      "staged_readahead": 0,
-                      "queue_wait_p50_ms": 0.0, "queue_wait_p99_ms": 0.0,
-                      "e2e_p50_ms": 0.0, "e2e_p99_ms": 0.0}
+                      "staged_readahead": 0}
         # pressure policy: level 1 (always on) spills *preempted*
         # requests' idle pages to the host tier when admission blocks;
         # level 2 (kv_pressure opt-in) additionally preempts-with-spill
@@ -398,6 +398,7 @@ class ServeEngine:
         self._pump: dict[int, _PendingPrefill] = {}
         self._lat_wait: list[float] = []
         self._lat_e2e: list[float] = []
+        self._lat_ttft: list[float] = []
 
     # -------------------------------------------------------- scheduling
     def submit(self, req: Request) -> None:
@@ -657,60 +658,64 @@ class ServeEngine:
         returned last-token logits are sliced at the true position.  A
         prompt that lands exactly on its bucket skips the mask entirely
         (bit-identical to the legacy exact-length path)."""
-        s = len(prompt)
-        bucket = prefill_bucket(s, self.max_len)
-        exact = s == bucket
-        key = (bucket, exact)
-        fn = self._prefill_cache.get(key)
-        if fn is None:
+        with span("engine.prefill"):
+            s = len(prompt)
+            bucket = prefill_bucket(s, self.max_len)
+            exact = s == bucket
+            key = (bucket, exact)
+            fn = self._prefill_cache.get(key)
+            if fn is None:
+                if exact:
+                    fn = jax.jit(
+                        lambda p, t: M.forward(self.cfg, p, {"tokens": t},
+                                               remat=False, collect_cache=True,
+                                               last_only=True)[:2])
+                else:
+                    fn = jax.jit(
+                        lambda p, t, n: M.forward(self.cfg, p, {"tokens": t},
+                                                  remat=False,
+                                                  collect_cache=True,
+                                                  last_only=True,
+                                                  true_len=n)[:2])
+                self._prefill_cache[key] = fn
             if exact:
-                fn = jax.jit(
-                    lambda p, t: M.forward(self.cfg, p, {"tokens": t},
-                                           remat=False, collect_cache=True,
-                                           last_only=True)[:2])
-            else:
-                fn = jax.jit(
-                    lambda p, t, n: M.forward(self.cfg, p, {"tokens": t},
-                                              remat=False,
-                                              collect_cache=True,
-                                              last_only=True,
-                                              true_len=n)[:2])
-            self._prefill_cache[key] = fn
-        if exact:
-            return fn(self.params, jnp.asarray(np.asarray(prompt)[None]))
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, :s] = np.asarray(prompt)
-        return fn(self.params, jnp.asarray(toks), jnp.asarray(s, jnp.int32))
+                return fn(self.params, jnp.asarray(np.asarray(prompt)[None]))
+            toks = np.zeros((1, bucket), np.int32)
+            toks[0, :s] = np.asarray(prompt)
+            return fn(self.params, jnp.asarray(toks),
+                      jnp.asarray(s, jnp.int32))
 
     def _prefill_into_slot(self, slot: int, req: Request) -> None:
-        s = len(req.prompt)
-        req.t_admit = time.perf_counter()
-        logits, caches = self._prefill_forward(req.prompt)
-        if self.paged:
-            # chop the prefill cache into pages instead of a batch write;
-            # the request binds to its slot's data shard — page claims
-            # come from that shard's free list from here on
-            shard = self._slot_shard(slot)
-            self.kv.add_request(req.rid, shard=shard)
-            self._reserve(req.rid, self._pages_for(req), shard)
-            self.kv.ingest_prefill(req.rid, caches, s)
-            if self.fused:
-                # admission-time device sync: pages (HOT partials
-                # included) + recurrent-kind states move once, here — the
-                # decode loop itself never uploads payloads
-                self.kv.sync_request_to_device(req.rid)
-                if self.kv.state_layers:
-                    self.kv.write_state_slot(slot, req.rid)
-        else:
-            self._write_prefill_cache(slot, caches)
-        # apack: allow-transfer(admission event: first-token pick after a
-        # prefill forward; not in the steady-state decode loop)
-        next_tok = int(jnp.argmax(logits[0, -1]))
-        req.tokens.append(next_tok)
-        self.active[slot] = req
-        self.positions[slot] = s
-        self.last_tokens[slot, 0] = next_tok
-        self._slot_steps[slot] = 0
+        with span("engine.admit", rid=req.rid):
+            s = len(req.prompt)
+            req.t_admit = time.perf_counter()
+            logits, caches = self._prefill_forward(req.prompt)
+            if self.paged:
+                # chop the prefill cache into pages instead of a batch write;
+                # the request binds to its slot's data shard — page claims
+                # come from that shard's free list from here on
+                shard = self._slot_shard(slot)
+                self.kv.add_request(req.rid, shard=shard)
+                self._reserve(req.rid, self._pages_for(req), shard)
+                self.kv.ingest_prefill(req.rid, caches, s)
+                if self.fused:
+                    # admission-time device sync: pages (HOT partials
+                    # included) + recurrent-kind states move once, here — the
+                    # decode loop itself never uploads payloads
+                    self.kv.sync_request_to_device(req.rid)
+                    if self.kv.state_layers:
+                        self.kv.write_state_slot(slot, req.rid)
+            else:
+                self._write_prefill_cache(slot, caches)
+            # apack: allow-transfer(admission event: first-token pick after a
+            # prefill forward; not in the steady-state decode loop)
+            next_tok = int(jnp.argmax(logits[0, -1]))
+            req.tokens.append(next_tok)
+            req.t_first = time.perf_counter()
+            self.active[slot] = req
+            self.positions[slot] = s
+            self.last_tokens[slot, 0] = next_tok
+            self._slot_steps[slot] = 0
 
     def _write_prefill_cache(self, slot: int, caches) -> None:
         # write this sequence's prefill cache into the batch cache at `slot`
@@ -803,22 +808,23 @@ class ServeEngine:
         self.stats["resumed"] += 1
 
     def _retire(self) -> None:
-        for slot, req in enumerate(self.active):
-            if req is None:
-                continue
-            eos = self.eos_id if req.eos_id is None else req.eos_id
-            if (len(req.tokens) >= req.max_new_tokens
-                    or (eos is not None and req.tokens
-                        and req.tokens[-1] == eos)
-                    or self.positions[slot] >= self.max_len - 1):
-                req.done = True
-                req.t_done = time.perf_counter()
-                self._log_latency(req)
-                self.stats["completed"] += 1
-                self.active[slot] = None
-                if self.paged:
-                    self.kv.release(req.rid)
-                    self._unreserve(req.rid)
+        with span("engine.retire"):
+            for slot, req in enumerate(self.active):
+                if req is None:
+                    continue
+                eos = self.eos_id if req.eos_id is None else req.eos_id
+                if (len(req.tokens) >= req.max_new_tokens
+                        or (eos is not None and req.tokens
+                            and req.tokens[-1] == eos)
+                        or self.positions[slot] >= self.max_len - 1):
+                    req.done = True
+                    req.t_done = time.perf_counter()
+                    self._log_latency(req)
+                    self.stats["completed"] += 1
+                    self.active[slot] = None
+                    if self.paged:
+                        self.kv.release(req.rid)
+                        self._unreserve(req.rid)
 
     def _log_latency(self, req: Request) -> None:
         if req.t_submit <= 0.0:
@@ -826,20 +832,18 @@ class ServeEngine:
         t_admit = req.t_admit if req.t_admit > 0.0 else req.t_done
         self._lat_wait.append(max(t_admit - req.t_submit, 0.0))
         self._lat_e2e.append(max(req.t_done - req.t_submit, 0.0))
-        for name, vals in (("queue_wait", self._lat_wait),
-                           ("e2e", self._lat_e2e)):
-            self.stats[f"{name}_p50_ms"] = float(
-                np.percentile(vals, 50) * 1e3)
-            self.stats[f"{name}_p99_ms"] = float(
-                np.percentile(vals, 99) * 1e3)
+        if req.t_first > 0.0:
+            self._lat_ttft.append(max(req.t_first - req.t_submit, 0.0))
 
     def latency_stats(self) -> dict:
-        """Queue-wait and end-to-end latency percentiles (seconds) over
-        every completed request, monotonic-clock based (perf_counter) so
-        NTP slew can never report a negative latency.  The serving bench
-        and ``launch/serve`` consume this."""
+        """Queue-wait, time-to-first-token and end-to-end latency
+        percentiles (seconds) over every completed request,
+        monotonic-clock based (perf_counter) so NTP slew can never report
+        a negative latency.  The serving bench and ``launch/serve``
+        consume this."""
         out: dict = {"n": len(self._lat_e2e)}
         for name, vals in (("queue_wait", self._lat_wait),
+                           ("ttft", self._lat_ttft),
                            ("e2e", self._lat_e2e)):
             if vals:
                 out[f"{name}_p50"] = float(np.percentile(vals, 50))
@@ -899,8 +903,12 @@ class ServeEngine:
     # apack: hot-path-root
     def step(self) -> int:
         """One engine iteration.  Returns number of active sequences."""
-        if self.scheduler == "async":
-            return self._step_async()
+        with span("engine.step"):
+            if self.scheduler == "async":
+                return self._step_async()
+            return self._step_sync()
+
+    def _step_sync(self) -> int:
         t0 = time.perf_counter()
         if self.faults is not None:
             d = self.faults.step_delay()
@@ -942,17 +950,19 @@ class ServeEngine:
             # meta->decode->claim->append order.
             targets = self.kv.claim_append_targets(slot_rids)
             meta = self.kv.step_meta(slot_rids, self.max_len)
-            logits, toks_dev, self.kv.dev.planes, self.kv.dev_states = \
-                self._step_mesh(
-                    self.params, self.kv.dev.planes, self.kv.dev_states,
-                    meta, jnp.asarray(self.last_tokens),
-                    jnp.asarray(self.positions), targets)
+            with span("engine.decode_dispatch"):
+                logits, toks_dev, self.kv.dev.planes, self.kv.dev_states = \
+                    self._step_mesh(
+                        self.params, self.kv.dev.planes, self.kv.dev_states,
+                        meta, jnp.asarray(self.last_tokens),
+                        jnp.asarray(self.positions), targets)
             self.kv.note_appended(slot_rids)
-            # apack: allow-transfer(the step's one sanctioned sync: token ids
-            # must reach the host for EOS/retire — the greedy argmax runs
-            # inside the sharded program, so this pulls batch int32s, not
-            # the [batch, vocab] logits)
-            toks = np.asarray(toks_dev, np.int32)
+            with span("engine.token_pull"):
+                # apack: allow-transfer(the step's one sanctioned sync: token
+                # ids must reach the host for EOS/retire — the greedy argmax
+                # runs inside the sharded program, so this pulls batch
+                # int32s, not the [batch, vocab] logits)
+                toks = np.asarray(toks_dev, np.int32)
         elif self.fused:
             # device-resident hot path: pages stay on device, attention
             # gather-decodes them in the fused kernel, and the new token's
@@ -960,18 +970,21 @@ class ServeEngine:
             # per-step host<->device traffic is the i32 page-table meta
             # up and the sampled logits down
             meta = self.kv.step_meta(slot_rids, self.max_len)
-            logits, new_cache = self._decode_paged(
-                self.params, self.kv.dev.planes, self.kv.dev_states, meta,
-                jnp.asarray(self.last_tokens), jnp.asarray(self.positions))
+            with span("engine.decode_dispatch"):
+                logits, new_cache = self._decode_paged(
+                    self.params, self.kv.dev.planes, self.kv.dev_states,
+                    meta, jnp.asarray(self.last_tokens),
+                    jnp.asarray(self.positions))
             targets = self.kv.claim_append_targets(slot_rids)
             self.kv.dev.planes = self._append(self.kv.dev.planes,
                                               new_cache, targets)
             self.kv.dev_states = M.states_from_step(self.cfg, new_cache)
             self.kv.note_appended(slot_rids)
-            # apack: allow-transfer(the step's one sanctioned sync: token ids
-            # must reach the host for EOS/retire; the d2h ledger and the
-            # zero-device_get gates account for exactly this pull)
-            toks = np.asarray(jnp.argmax(logits[:, 0], axis=-1), np.int32)
+            with span("engine.token_pull"):
+                # apack: allow-transfer(the step's one sanctioned sync: token
+                # ids must reach the host for EOS/retire; the d2h ledger and
+                # the zero-device_get gates account for exactly this pull)
+                toks = np.asarray(jnp.argmax(logits[:, 0], axis=-1), np.int32)
         else:
             if self.paged:
                 # attention read: rebuild the dense int8 cache from the
@@ -1039,35 +1052,31 @@ class ServeEngine:
         Greedy tokens are bit-identical to the sync engine: the same
         kernels see the same per-slot inputs, only host work moved."""
         t0 = time.perf_counter()
-        if self.faults is not None:
-            d = self.faults.step_delay()
-            if d:
-                time.sleep(d)
-        self._overlap_host_work()
-        t_host = time.perf_counter()
-        self._collect()
-        t_collect = time.perf_counter()
-        self._retire()
-        self._check_deadlines()
-        self._admit_async()
-        n_active = sum(r is not None for r in self.active)
-        if n_active:
-            try:
-                self._dispatch()
-            except m.PageIntegrityError as e:
-                # step_meta read guards fire before any page mutation;
-                # fail the owner and re-dispatch for the survivors
-                self._handle_integrity_failure(e)
-                n_active = sum(r is not None for r in self.active)
-                if n_active:
+        with span("engine.overlap_host"):
+            if self.faults is not None:
+                d = self.faults.step_delay()
+                if d:
+                    time.sleep(d)
+            self._overlap_host_work()
+        with span("engine.collect"):
+            self._collect()
+        with span("engine.schedule_dispatch"):
+            self._retire()
+            self._check_deadlines()
+            self._admit_async()
+            n_active = sum(r is not None for r in self.active)
+            if n_active:
+                try:
                     self._dispatch()
+                except m.PageIntegrityError as e:
+                    # step_meta read guards fire before any page mutation;
+                    # fail the owner and re-dispatch for the survivors
+                    self._handle_integrity_failure(e)
+                    n_active = sum(r is not None for r in self.active)
+                    if n_active:
+                        self._dispatch()
         if self.watchdog is not None:
-            ev = self.watchdog.observe(
-                time.perf_counter() - t0,
-                phases={"overlap_host": t_host - t0,
-                        "collect": t_collect - t_host,
-                        "schedule_dispatch":
-                            time.perf_counter() - t_collect})
+            ev = self.watchdog.observe(time.perf_counter() - t0)
             if ev is not None and ev.kind == "hung":
                 self._on_hung(ev)
         return n_active
@@ -1170,6 +1179,7 @@ class ServeEngine:
         if self.kv.state_layers:
             self.kv.write_state_slot(slot, req.rid)
         req.tokens.append(p.tok)
+        req.t_first = time.perf_counter()
         self.active[slot] = req
         self.positions[slot] = p.s
         self.last_tokens[slot, 0] = p.tok
@@ -1240,9 +1250,10 @@ class ServeEngine:
         ``_InFlight`` for collect."""
         slot_rids = [r.rid if r is not None else None for r in self.active]
         meta = self.kv.step_meta(slot_rids, self.max_len)
-        logits, new_cache = self._decode_paged(
-            self.params, self.kv.dev.planes, self.kv.dev_states, meta,
-            jnp.asarray(self.last_tokens), jnp.asarray(self.positions))
+        with span("engine.decode_dispatch"):
+            logits, new_cache = self._decode_paged(
+                self.params, self.kv.dev.planes, self.kv.dev_states, meta,
+                jnp.asarray(self.last_tokens), jnp.asarray(self.positions))
         targets = self.kv.claim_append_targets(slot_rids)
         self.kv.dev.planes = self._append(self.kv.dev.planes,
                                           new_cache, targets)
@@ -1261,9 +1272,12 @@ class ServeEngine:
         if inf is None:
             return
         self._inflight = None
-        # apack: allow-transfer(collect IS the sync point: the async loop's one
-        # sanctioned token-id pull, after the step finished computing)
-        toks = np.asarray(jnp.argmax(inf.logits[:, 0], axis=-1), np.int32)
+        with span("engine.token_pull"):
+            # apack: allow-transfer(collect IS the sync point: the async
+            # loop's one sanctioned token-id pull, after the step finished
+            # computing)
+            toks = np.asarray(jnp.argmax(inf.logits[:, 0], axis=-1),
+                              np.int32)
         self.kv.note_appended(inf.slot_rids)
         self.last_logits = inf.logits
         for slot, req in enumerate(inf.slot_reqs):
