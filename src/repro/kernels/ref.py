@@ -399,6 +399,145 @@ def pack_raw(values: jax.Array, n_steps: int, bits: int = 8):
     return plane
 
 
+ENCODER_ROW = 17 + 16 + 17
+
+
+def encoder_row(t: ApackTable) -> np.ndarray:
+    """One table as the i32[ENCODER_ROW] row ``encode_pages`` reads:
+    ``v_min`` [17] | ``ol`` [16] | ``cum`` [17]."""
+    return np.concatenate([np.asarray(t.v_min, np.int32),
+                           np.asarray(t.ol, np.int32),
+                           np.asarray(t.cum, np.int32)])
+
+
+def _put_row(plane, widx, word, do):
+    """Write ``word`` at row ``widx`` of each lane where ``do``, as a
+    select over every row: the cost does not grow with the lane count the
+    way a scatter's does.  plane: u32[P, W, S]; the rest [P, S].  A
+    ``widx`` past the plane writes nothing (only stored-mode lanes, whose
+    planes are replaced, get there)."""
+    rows = jax.lax.broadcasted_iota(I32, plane.shape, 1)
+    hit = (rows == widx[:, None, :]) & do[:, None, :]
+    return jnp.where(hit, word[:, None, :], plane)
+
+
+def _flush_rows(plane, widx, buf_lo, buf_hi, buflen):
+    """``_flush`` over [P, S] lanes through ``_put_row``."""
+    do = buflen >= 32
+    plane = _put_row(plane, widx, buf_lo, do)
+    buf_lo = jnp.where(do, buf_hi, buf_lo)
+    buf_hi = jnp.where(do, U32(0), buf_hi)
+    buflen = jnp.where(do, buflen - 32, buflen)
+    return plane, widx + do.astype(I32), buf_lo, buf_hi, buflen
+
+
+@jax.jit
+def encode_pages(values: jax.Array, rows: jax.Array):
+    """Encode P pages of 8-bit values at once, each under its own table:
+    the full encoder (``encode``: AC coding, verbatim pack, stored-mode
+    selection) in one program, bit-identical to ``encode`` page by page.
+
+    Args:
+      values: u8[P, S, E], S streams of E values per page.
+      rows:   i32[P, ENCODER_ROW], each page's ``encoder_row``.
+
+    Returns (sym u32[P, Ws, S], ofs u32[P, Wo, S], sym_bits i32[P, S],
+    ofs_bits i32[P, S], stored bool[P, S]).
+
+    The serial scan runs E steps whatever P is.  Its body holds no
+    scatter or gather: the symbol search (a comparison count, as in
+    ``decode``) and the table picks run before it over every value, as
+    compares and selects, and words are written by ``_put_row``.
+    """
+    P, S, E = values.shape
+    bits = 8
+    Ws = sym_capacity_words(E)
+    Wo = ofs_capacity_words(E, bits)
+    col = [rows[:, r:r + 1] for r in range(rows.shape[1])]
+    v_min, ol, cum = col[0:17], col[17:33], col[33:50]
+
+    # symbol search and table picks, hoisted out of the serial scan: s is
+    # the last range whose start is <= v (starts ascend), found by
+    # comparisons and selects.  Each value's (offset, offset length,
+    # cum[s], cum[s+1] - 1) is packed into one u32 (8 + 4 + 10 + 10 bits).
+    v = values.astype(I32)
+    ol_s, vlo, clo, chi = (jnp.broadcast_to(x[:, :, None], v.shape)
+                           for x in (ol[0], v_min[0], cum[0], cum[1]))
+    for r in range(1, 16):
+        ge = v >= v_min[r][:, :, None]
+        ol_s = jnp.where(ge, ol[r][:, :, None], ol_s)
+        vlo = jnp.where(ge, v_min[r][:, :, None], vlo)
+        clo = jnp.where(ge, cum[r][:, :, None], clo)
+        chi = jnp.where(ge, cum[r + 1][:, :, None], chi)
+    sym = ((v - vlo) | (ol_s << 8) | (clo << 12) | ((chi - 1) << 22))
+    sym = jnp.transpose(sym.astype(U32), (2, 0, 1))          # [E, P, S]
+
+    def step(carry, x):
+        (low, high, pending, overflow,
+         s_plane, s_widx, s_lo, s_hi, s_len, s_bits,
+         o_plane, o_widx, o_lo, o_hi, o_len, o_bits) = carry
+        off = x & U32(0xFF)
+        ol_s = ((x >> 8) & U32(0xF)).astype(I32)
+        clo = ((x >> 12) & U32(0x3FF)).astype(I32)
+        chi = (x >> 22).astype(I32) + 1
+        o_lo, o_hi, o_len = _append(o_lo, o_hi, o_len, off, ol_s)
+        o_bits = o_bits + ol_s
+        o_plane, o_widx, o_lo, o_hi, o_len = _flush_rows(
+            o_plane, o_widx, o_lo, o_hi, o_len)
+        rng = high - low + 1
+        high2 = low + ((rng * chi) >> PCOUNT_BITS) - 1
+        low2 = low + ((rng * clo) >> PCOUNT_BITS)
+        low, high, pending, pat1, k1, pat2, k2 = encode_renorm(
+            low2, high2, pending)
+        s_lo, s_hi, s_len = _append(s_lo, s_hi, s_len, pat1, k1)
+        s_plane, s_widx, s_lo, s_hi, s_len = _flush_rows(
+            s_plane, s_widx, s_lo, s_hi, s_len)
+        s_lo, s_hi, s_len = _append(s_lo, s_hi, s_len, pat2, k2)
+        s_plane, s_widx, s_lo, s_hi, s_len = _flush_rows(
+            s_plane, s_widx, s_lo, s_hi, s_len)
+        s_bits = s_bits + k1 + k2
+        overflow = overflow | (pending > MAX_PENDING)
+        return (low, high, pending, overflow,
+                s_plane, s_widx, s_lo, s_hi, s_len, s_bits,
+                o_plane, o_widx, o_lo, o_hi, o_len, o_bits), None
+
+    zeros = jnp.zeros((P, S), I32)
+    zerosu = jnp.zeros((P, S), U32)
+    init = (zeros, jnp.full((P, S), TOP, I32), zeros, jnp.zeros((P, S), bool),
+            jnp.zeros((P, Ws, S), U32), zeros, zerosu, zerosu, zeros, zeros,
+            jnp.zeros((P, Wo, S), U32), zeros, zerosu, zerosu, zeros, zeros)
+    carry, _ = jax.lax.scan(step, init, sym)
+    (low, high, pending, overflow,
+     s_plane, s_widx, s_lo, s_hi, s_len, s_bits,
+     o_plane, o_widx, o_lo, o_hi, o_len, o_bits) = carry
+
+    # termination, as encode_ac
+    pending = pending + 1
+    b = (low >= QUARTER).astype(U32)
+    inv_run = (shl32(jnp.ones_like(b), pending) - U32(1)) * (U32(1) - b)
+    k = 1 + pending
+    s_lo, s_hi, s_len = _append(s_lo, s_hi, s_len, b | (inv_run << 1), k)
+    s_bits = s_bits + k
+    for _ in range(3):
+        s_plane, s_widx, s_lo, s_hi, s_len = _flush_rows(
+            s_plane, s_widx, s_lo, s_hi, s_len)
+    s_plane = _put_row(s_plane, s_widx, s_lo, s_len > 0)
+    o_plane = _put_row(o_plane, o_widx, o_lo, o_len > 0)
+
+    # verbatim pack (pack_raw): four values a word, the first lowest
+    v = jnp.pad(values.astype(U32), ((0, 0), (0, 0), (0, -E % 4)))
+    v = v.reshape(P, S, -1, 4)
+    raw = v[..., 0] | (v[..., 1] << 8) | (v[..., 2] << 16) | (v[..., 3] << 24)
+    raw = jnp.pad(jnp.transpose(raw, (0, 2, 1)),
+                  ((0, 0), (0, Wo - raw.shape[2]), (0, 0)))
+
+    stored = overflow | ((s_bits + o_bits) >= E * bits)
+    st = stored[:, None, :]
+    return (jnp.where(st, U32(0), s_plane), jnp.where(st, raw, o_plane),
+            jnp.where(stored, 0, s_bits), jnp.where(stored, E * bits, o_bits),
+            stored)
+
+
 def encode(values: jax.Array, table: TableArrays, n_steps: int,
            bits: int = 8):
     """Full encoder: AC encode + per-stream stored-mode selection.

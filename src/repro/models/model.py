@@ -855,6 +855,26 @@ def prefill(cfg: ModelConfig, params: dict, batch: dict,
 ATTN_KINDS = ("global", "local")
 STATE_KINDS = ("recurrent", "mlstm", "slstm")
 
+# Chunk shapes of the batched page encode (``PagedKVCache._pack_pending``),
+# in page-kinds (the K or the V half of one page).  A decode step seals one
+# page per layer of the few sequences that crossed a page boundary; an
+# admission one per prompt page and layer.  The encode's serial scan takes
+# as many steps whatever the chunk holds, so a large chunk costs about as
+# much as ``SEAL_SMALL_PER_LARGE`` small ones.  Both sizes are even.
+SEAL_CHUNK_SMALL = 16
+SEAL_CHUNK_LARGE = 128
+SEAL_SMALL_PER_LARGE = 4
+
+
+def seal_chunks(n: int) -> list[int]:
+    """Chunk sizes that cover ``n`` page-kinds: large chunks, and small
+    ones for the rest where fewer than ``SEAL_SMALL_PER_LARGE`` do."""
+    large, rest = divmod(n, SEAL_CHUNK_LARGE)
+    small = -(-rest // SEAL_CHUNK_SMALL)
+    if small >= SEAL_SMALL_PER_LARGE:
+        large, small = large + 1, 0
+    return [SEAL_CHUNK_LARGE] * large + [SEAL_CHUNK_SMALL] * small
+
 
 def _layer_kinds(cfg: ModelConfig) -> list[str]:
     """Network-layer kind list: prefix layers first, then the scanned
@@ -1033,6 +1053,8 @@ class PagedKVCache:
         self.hists = np.zeros((self.n_layers, 2, 256), np.int64)
         self.hist_pages = np.zeros((self.n_layers, 2), np.int32)
         self._cold: list[set[int]] = [set() for _ in range(self.n_layers)]
+        # sealed COLD pages waiting for ``_pack_pending``: pid -> layer
+        self._pending: dict[int, int] = {}
         self._packed: list[set[int]] = [set() for _ in range(self.n_layers)]
         self._table_stack = None   # lazy [(G+1)*2*n_layers, ...] np stack
         # generation-versioned table pool: ``self.tables`` is always the
@@ -1080,6 +1102,7 @@ class PagedKVCache:
         self.seq_len: dict[int, int] = {}
         self.traffic = {"kv_raw_bytes": 0, "kv_read_bytes": 0,
                         "kv_table_bytes": 0, "kv_pages_packed": 0,
+                        "kv_seal_encode_calls": 0,
                         "kv_raw_bytes_global": 0, "kv_read_bytes_global": 0,
                         "kv_raw_bytes_local": 0, "kv_read_bytes_local": 0,
                         "state_raw_bytes": 0, "state_snapshot_bytes": 0,
@@ -1281,6 +1304,7 @@ class PagedKVCache:
         for layer in self.attn_layers:
             self._append_layer_token(rid, layer, kq[layer], vq[layer],
                                      ks[layer], vs[layer], t)
+        self._pack_pending()
         self.seq_len[rid] = t + 1
         self.evict_rolled(rid)
 
@@ -1466,6 +1490,7 @@ class PagedKVCache:
                 else:
                     kq, vq, kss, vss = k[t], v[t], ksc[t], vsc[t]
                 self._append_layer_token(rid, layer, kq, vq, kss, vss, t)
+        self._pack_pending()
 
     def finish_prefill(self, rid: int, view: dict, s: int) -> None:
         """Final chunk bookkeeping: store recurrent-kind final states,
@@ -1478,7 +1503,8 @@ class PagedKVCache:
     # ------------------------------------------------- seal/calibrate/pack
     def _seal(self, layer: int, pid: int) -> None:
         """Full HOT page -> COLD: re-quantize to one scale per (page, head)
-        — scale amortization — then calibrate or pack."""
+        — scale amortization — then calibrate, or queue the page for
+        ``_pack_pending``."""
         with span("kv.seal"):
             from repro.core import quant, tables as ctables
             from repro.core.tables import TABLE_OVERHEAD_BITS
@@ -1510,7 +1536,7 @@ class PagedKVCache:
                                                                  minlength=256)
                 self.drift_pages[layer] += 1
                 self._drift_changed.add(layer)
-                self._pack(layer, pid)
+                self._pending[pid] = layer
                 return
             for kind in (0, 1):
                 u = quant.to_unsigned(q2[kind]).reshape(-1)
@@ -1531,36 +1557,62 @@ class PagedKVCache:
                 self._tables_dirty = True
                 self.traffic["kv_table_bytes"] += 2 * TABLE_OVERHEAD_BITS // 8
                 for cold_pid in sorted(self._cold[layer]):
-                    self._pack(layer, cold_pid)
+                    self._pending[cold_pid] = layer
 
-    def _pack(self, layer: int, pid: int) -> None:
-        """COLD -> PACKED: APack-encode both kinds with the layer's
-        activation tables into the pool's fixed-capacity planes."""
-        from repro.core import quant
+    def _pack_pending(self) -> None:
+        """COLD -> PACKED for every page ``_seal`` queued: APack-encode
+        both kinds of all of them with their layers' activation tables, in
+        ``seal_chunks`` device calls of ``kernels/ref.encode_pages`` (one
+        push of values, one of table rows and one pull of the planes
+        each), then store each page's planes, table generation and CRC.
+        Runs at the end of every public method that can seal, so no other
+        method sees a queued page."""
         from repro.kernels import ref as _codec
+        todo = [(pid, layer) for pid, layer in self._pending.items()
+                if pid in self._cold[layer]]
+        self._pending.clear()
+        if not todo:
+            return
         pool = self.pool
-        with span("kv.seal.encode"):
-            outs = []
-            for kind in (0, 1):
-                vals = quant.to_unsigned(pool.cold_q[kind, pid]).reshape(
-                    pool.n_streams, pool.elems_per_stream)
-                ta = _codec.TableArrays.from_table(self.tables[layer][kind])
-                planes = _codec.encode(jnp.asarray(vals.astype(np.int32)), ta,
-                                       pool.elems_per_stream, 8)
-                # apack: allow-transfer(page-seal event: encoding a sealed COLD
-                # page is host work off the step critical path)
-                outs.append(tuple(np.asarray(p) for p in planes))
-        pool.pack(pid, tuple(np.stack([o[i] for o in outs])
-                             for i in range(5)))
-        self._cold[layer].discard(pid)
-        self._packed[layer].add(pid)
-        # stamp the generation the coding table belongs to (earliest
-        # generation holding this content — stays valid across later
-        # refreshes of *other* layers thanks to copy-forward stacking)
-        self.page_gen[pid] = int(self.table_gen[layer])
-        self.page_crc[pid] = self._plane_crc(pid)
-        self._mark_dirty(pid)
-        self.traffic["kv_pages_packed"] += 1
+        with span("kv.seal"):
+            pids = [pid for pid, _ in todo]
+            # page-kind j = 2 * page + kind; int8 viewed as uint8 is
+            # quant.to_unsigned
+            vals = pool.cold_q[:, pids].view(np.uint8).swapaxes(0, 1)
+            vals = vals.reshape(-1, pool.n_streams, pool.elems_per_stream)
+            layer_rows = {layer: [_codec.encoder_row(t)
+                                  for t in self.tables[layer]]
+                          for layer in {layer for _, layer in todo}}
+            rows = np.stack([r for _, layer in todo
+                             for r in layer_rows[layer]])
+            at = 0
+            for size in seal_chunks(len(rows)):
+                # the last chunk is padded with zero pages, discarded; the
+                # sizes are even, so a page's K and V share a chunk
+                n = min(size, len(rows) - at)
+                v = np.zeros((size,) + vals.shape[1:], np.uint8)
+                r = np.repeat(rows[at:at + 1], size, axis=0)
+                v[:n], r[:n] = vals[at:at + n], rows[at:at + n]
+                with span("kv.seal.encode"):
+                    # apack: allow-transfer(page-seal event: one push of the
+                    # chunk's values and table rows, one pull of its planes)
+                    out = jax.device_get(_codec.encode_pages(
+                        jnp.asarray(v), jnp.asarray(r)))
+                self.traffic["kv_seal_encode_calls"] += 1
+                for q in range(0, n, 2):
+                    pid, layer = todo[(at + q) // 2]
+                    pool.pack(pid, tuple(o[q:q + 2] for o in out))
+                    self._cold[layer].discard(pid)
+                    self._packed[layer].add(pid)
+                    # stamp the generation the coding table belongs to
+                    # (earliest generation holding this content — stays
+                    # valid across later refreshes of *other* layers
+                    # thanks to copy-forward stacking)
+                    self.page_gen[pid] = int(self.table_gen[layer])
+                    self.page_crc[pid] = self._plane_crc(pid)
+                    self._mark_dirty(pid)
+                    self.traffic["kv_pages_packed"] += 1
+                at += n
 
     def _plane_crc(self, pid: int) -> int:
         """Integrity checksum of a PACKED page's compressed planes + page
@@ -2001,42 +2053,49 @@ class PagedKVCache:
         table entries were rewritten as they were adopted) so release
         cleans up normally and neighbors never see the corruption."""
         restored: list[int] = []
-        for layer in self.attn_layers:
-            pids = self.page_tables[rid][layer]
-            for i, entry in enumerate(pids):
-                if entry >= 0:
-                    continue
-                handle = -entry - 1
-                try:
-                    rec = self.spill_tier.get(handle)
-                except m.PageIntegrityError as e:
-                    self.traffic["kv_integrity_failures"] += 1
-                    self.traffic["kv_quarantined_pages"] += 1
-                    raise m.PageIntegrityError(
-                        f"unspill of rid={rid} layer={layer} page {i}: "
-                        f"{e}", rid=rid, layer=layer, handle=handle) from e
-                pid = self.pool.adopt(rec.state, rec.fill, rec.payload,
-                                      shard=self.request_shard.get(rid, 0))
-                pids[i] = pid
-                self.page_gen[pid] = rec.gen
-                if rec.state == m.PAGE_PACKED:
-                    self._packed[layer].add(pid)
-                    self.page_crc[pid] = self._plane_crc(pid)
-                    if rec.gen < int(self.table_gen[layer]):
-                        # packed under a since-refreshed table: still
-                        # decodable via its generation row; queue for the
-                        # budgeted migration like any stale resident page
-                        self._repack_queue.append((layer, pid))
-                elif rec.state == m.PAGE_COLD:
-                    self._cold[layer].add(pid)
-                    if self.tables[layer][0] is not None:
-                        self._pack(layer, pid)   # table arrived while parked
-                self._mark_dirty(pid)
-                self.spill_tier.drop(handle)
-                self.traffic["kv_readahead_pages"] += 1
+        try:
+            for layer in self.attn_layers:
+                pids = self.page_tables[rid][layer]
+                for i, entry in enumerate(pids):
+                    if entry >= 0:
+                        continue
+                    handle = -entry - 1
+                    try:
+                        rec = self.spill_tier.get(handle)
+                    except m.PageIntegrityError as e:
+                        self.traffic["kv_integrity_failures"] += 1
+                        self.traffic["kv_quarantined_pages"] += 1
+                        raise m.PageIntegrityError(
+                            f"unspill of rid={rid} layer={layer} page {i}: "
+                            f"{e}", rid=rid, layer=layer, handle=handle) from e
+                    pid = self.pool.adopt(rec.state, rec.fill, rec.payload,
+                                          shard=self.request_shard.get(rid, 0))
+                    pids[i] = pid
+                    self.page_gen[pid] = rec.gen
+                    if rec.state == m.PAGE_PACKED:
+                        self._packed[layer].add(pid)
+                        self.page_crc[pid] = self._plane_crc(pid)
+                        if rec.gen < int(self.table_gen[layer]):
+                            # packed under a since-refreshed table: still
+                            # decodable via its generation row; queue for the
+                            # budgeted migration like any stale resident page
+                            self._repack_queue.append((layer, pid))
+                    elif rec.state == m.PAGE_COLD:
+                        self._cold[layer].add(pid)
+                        if self.tables[layer][0] is not None:
+                            # table arrived while parked
+                            self._pending[pid] = layer
+                    self._mark_dirty(pid)
+                    self.spill_tier.drop(handle)
+                    self.traffic["kv_readahead_pages"] += 1
+                    restored.append(pid)
+        finally:
+            # readahead bytes are the restored pages as they now stand,
+            # packed where their table exists
+            self._pack_pending()
+            for pid in restored:
                 self.traffic["kv_readahead_bytes"] += \
                     self.pool.page_bytes(pid)
-                restored.append(pid)
         if restored:
             self.traffic["kv_readahead_calls"] += 1
             self._flush_device()              # one batched h2d, pre-kernel
@@ -2097,6 +2156,14 @@ class PagedKVCache:
                                     mesh=mesh)
         self.dev_states = init_state_store(self.cfg, max_batch)
         self._sync_tables_to_device()
+        # compile both chunk shapes of the seal's encode now, so that no
+        # seal compiles while serving
+        from repro.kernels import ref as _codec
+        shape = (self.pool.n_streams, self.pool.elems_per_stream)
+        for size in (SEAL_CHUNK_SMALL, SEAL_CHUNK_LARGE):
+            jax.block_until_ready(_codec.encode_pages(
+                jnp.zeros((size,) + shape, jnp.uint8),
+                jnp.zeros((size, _codec.ENCODER_ROW), jnp.int32)))
 
     def _mark_dirty(self, pid: int) -> None:
         if self.dev is not None:
@@ -2174,6 +2241,7 @@ class PagedKVCache:
                     d[k] = d[k].at[idx].set(self._put(v))
 
     def _flush_device(self) -> None:
+        self._pack_pending()
         if self.dev is None:
             return
         with span("kv.flush"):

@@ -8,14 +8,16 @@ full streams, across bit-widths, stored-mode fallbacks, and stream counts
 that don't tile the 128-lane kernel block.
 """
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core import ac_golden, distributions, format as fmt, tables
+from repro.core import ac_golden, distributions, format as fmt, quant, tables
 from repro.core.ac_golden import HALF, QUARTER, THREEQ, TOP
 from repro.kernels import ops, ref
 from repro.kernels import decompress_matmul as dm
+from repro.models import model as M
 
 
 def _wnc_renorm(low: int, high: int):
@@ -184,3 +186,134 @@ class TestTableMode:
         ca = np.diff(np.asarray(ta.cum))
         assert (cw == 0).any()
         assert (ca > 0).all()
+
+
+# ------------------------------------------------- batched page encoder
+def _overflow_table():
+    """16 ranges of 16 values; range 1 owns counts [256, 768), so a run
+    of its values keeps the interval on the midpoint and every step adds
+    a pending underflow bit until the encoder's cap trips."""
+    cum = [0, 256, 768] + [768 + 18 * i for i in range(1, 14)] + [1024]
+    return tables.ApackTable(v_min=tuple(range(0, 257, 16)),
+                             ol=(4,) * 16, cum=tuple(cum), bits=8,
+                             mode="activation")
+
+
+def _page_kinds(n, s, e, seed):
+    """``n`` page-kinds of ``s`` x ``e`` 8-bit values under four tables
+    (as layers and kinds have): two fitted, a uniform one whose streams
+    inflate to stored mode, and one on which one stream's pending run
+    overflows."""
+    rng = np.random.default_rng(seed)
+    fitted = [tables.table_for(
+        quant.to_unsigned(np.clip(np.round(rng.normal(0, sp, 4096)),
+                                  -127, 127).astype(np.int8)).astype(np.int64),
+        is_activation=True) for sp in (3, 40)]
+    ts = [fitted[0], fitted[1], tables.uniform_table(), _overflow_table()]
+    pages, pts = [], []
+    for i in range(n):
+        k = i % 4
+        if k < 2:
+            sp = (3, 40)[k]
+            q = np.clip(np.round(rng.normal(0, sp, (s, e))), -127, 127)
+            u = quant.to_unsigned(q.astype(np.int8))
+        elif k == 2:
+            u = rng.integers(0, 256, (s, e))
+        else:
+            u = rng.integers(0, 256, (s, e))
+            u[0] = rng.integers(16, 32, e)            # the overflow stream
+        pages.append(u.astype(np.uint8))
+        pts.append(ts[k])
+    return np.stack(pages), pts
+
+
+def _encode_chunk(pages, pts, size):
+    """``pages`` in one chunk of ``size`` page-kinds, padded with zero
+    pages as ``PagedKVCache._pack_pending`` pads its last chunk."""
+    n = len(pages)
+    v = np.zeros((size,) + pages.shape[1:], np.uint8)
+    v[:n] = pages
+    rows = np.stack([ref.encoder_row(t) for t in pts])
+    r = np.concatenate([rows, np.repeat(rows[:1], size - n, axis=0)])
+    out = ref.encode_pages(jnp.asarray(v), jnp.asarray(r))
+    return [np.asarray(o)[:n] for o in out]
+
+
+class TestBatchedPageEncoder:
+    @pytest.mark.parametrize("size", [M.SEAL_CHUNK_SMALL, M.SEAL_CHUNK_LARGE])
+    def test_matches_per_page_encode_and_golden(self, size):
+        """A chunk at each compiled shape, its last slots zero padding:
+        every page equals ``ref.encode`` and ``format.compress`` of that
+        page alone, stored-mode and overflow streams included."""
+        s, e = 2, 128
+        pages, pts = _page_kinds(size - 3, s, e, seed=size)
+        out = _encode_chunk(pages, pts, size)
+        assert out[4].any() and not out[4].all()
+        overflowed = 0
+        for p, (vals, t) in enumerate(zip(pages, pts)):
+            want = ref.encode(jnp.asarray(vals.astype(np.int32)),
+                              ref.TableArrays.from_table(t), e, 8)
+            for got, w in zip(out, want):
+                w = np.asarray(w)
+                assert got[p].dtype == w.dtype
+                assert np.array_equal(got[p], w), p
+            ct = fmt.compress(vals.reshape(-1).astype(np.int64), t, bits=8,
+                              elems_per_stream=e)
+            assert np.array_equal(out[2][p], ct.sym_bits)
+            assert np.array_equal(out[3][p], ct.ofs_bits)
+            assert np.array_equal(out[4][p], ct.stored)
+            ws, wo = ct.sym_plane.shape[0], ct.ofs_plane.shape[0]
+            assert np.array_equal(out[0][p][:ws], ct.sym_plane)
+            assert not out[0][p][ws:].any()
+            assert np.array_equal(out[1][p][:wo], ct.ofs_plane)
+            assert not out[1][p][wo:].any()
+            overflowed += t == pts[3] and bool(out[4][p][0])
+        assert overflowed > 0
+
+    def test_page_geometry_of_the_served_cache(self):
+        """128 streams of 128 values (a 16-token page of 8 heads x 128):
+        a small chunk of mixed tables against ``ref.encode``."""
+        s = e = 128
+        pages, pts = _page_kinds(M.SEAL_CHUNK_SMALL - 1, s, e, seed=9)
+        out = _encode_chunk(pages, pts, M.SEAL_CHUNK_SMALL)
+        for p, (vals, t) in enumerate(zip(pages, pts)):
+            want = ref.encode(jnp.asarray(vals.astype(np.int32)),
+                              ref.TableArrays.from_table(t), e, 8)
+            for got, w in zip(out, want):
+                assert np.array_equal(got[p], np.asarray(w)), p
+
+    def test_scan_body_has_no_scatter_or_gather(self):
+        """The serial step's cost must not grow with the lane count the
+        way a scatter's or a gather's does: its body holds neither."""
+        v = jnp.zeros((4, 2, 128), jnp.uint8)
+        r = jnp.zeros((4, ref.ENCODER_ROW), jnp.int32)
+        jaxpr = jax.make_jaxpr(ref.encode_pages)(v, r).jaxpr
+
+        def eqns(j):
+            for eq in j.eqns:
+                yield eq
+                for v in eq.params.values():
+                    for sub in (v if isinstance(v, (tuple, list)) else [v]):
+                        sub = getattr(sub, "jaxpr", sub)
+                        if hasattr(sub, "eqns"):
+                            yield from eqns(sub)
+
+        scans = [eq for eq in eqns(jaxpr) if eq.primitive.name == "scan"]
+        assert len(scans) == 1
+        body = {eq.primitive.name
+                for eq in eqns(scans[0].params["jaxpr"].jaxpr)}
+        assert not {p for p in body if "scatter" in p or "gather" in p}, body
+        assert "select_n" in body
+
+    def test_seal_chunks_cover_with_two_shapes(self):
+        small, large = M.SEAL_CHUNK_SMALL, M.SEAL_CHUNK_LARGE
+        assert M.seal_chunks(0) == []
+        assert M.seal_chunks(1) == [small]
+        assert small % 2 == 0 and large % 2 == 0   # K and V share a chunk
+        for n in range(1, 3 * large + 5):
+            plan = M.seal_chunks(n)
+            assert set(plan) <= {small, large}
+            assert sum(plan) >= n
+            # a chunk is all padding only if it is the single one
+            assert sum(plan) - plan[-1] < n
+            assert plan.count(small) < M.SEAL_SMALL_PER_LARGE

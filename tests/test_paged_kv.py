@@ -2,6 +2,8 @@
 pool, the Pallas gather-decode kernel, decode parity with the raw int8-KV
 path, and ServeEngine scheduling edge cases."""
 import dataclasses
+import inspect
+import time
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +18,7 @@ from repro.kernels.paged_decode import (gather_bucket, gather_decode,
                                         gather_decode_pallas)
 from repro.models import model as M
 from repro.models import modules as m
+from repro.runtime import spans
 from repro.serve import Request, ServeEngine
 
 KEY = jax.random.PRNGKey(0)
@@ -393,3 +396,182 @@ class TestPagedEngineScheduling:
         eng.run_until_drained(max_steps=200)
         for r, ref_toks in zip(reqs, seq_out):
             assert r.tokens[:4] == ref_toks, (r.tokens, ref_toks)
+
+
+# ---------------------------------------------------- batched page seal
+def _record_packs(kv):
+    """Wrap ``kv.pool.pack`` to keep each page's COLD payload as it was
+    when the page was packed (``pack`` scrubs it)."""
+    seen = {}
+    inner = kv.pool.pack
+
+    def pack(pid, planes):
+        seen[pid] = kv.pool.cold_q[:, pid].copy()
+        inner(pid, planes)
+
+    kv.pool.pack = pack
+    return seen
+
+
+def _assert_packed_alone(kv, seen):
+    """Every recorded page holds the planes, CRC and table generation that
+    packing it alone with ``ref.encode`` gives."""
+    pool = kv.pool
+    e = pool.elems_per_stream
+    layer_of = {pid: layer for layer in kv.attn_layers
+                for pid in kv._packed[layer]}
+    assert seen and set(seen) <= set(layer_of)
+    for pid, cold in seen.items():
+        layer = layer_of[pid]
+        outs = []
+        for kind in (0, 1):
+            vals = quant.to_unsigned(cold[kind]).reshape(pool.n_streams, e)
+            ta = _ref.TableArrays.from_table(kv.tables[layer][kind])
+            outs.append([np.asarray(x) for x in _ref.encode(
+                jnp.asarray(vals.astype(np.int32)), ta, e, 8)])
+        want = {name: np.stack([o[i] for o in outs]).astype(
+                    getattr(pool, name).dtype)
+                for i, name in enumerate(("sym", "ofs", "sym_bits",
+                                          "ofs_bits", "stored"))}
+        for name, w in want.items():
+            assert np.array_equal(getattr(pool, name)[:, pid], w), (pid, name)
+        want["page_scale"] = pool.page_scale[:, pid]
+        assert int(kv.page_crc[pid]) == m.payload_crc(want)
+        assert int(kv.page_gen[pid]) == int(kv.table_gen[layer])
+        assert pool.state[pid] == m.PAGE_PACKED
+
+
+class TestBatchedPageSeal:
+    def test_admission_and_multi_slot_step_match_packing_alone(self):
+        """One step admits two prompts (every prompt page sealed) and
+        decodes a token that fills a page of both slots: the cache holds
+        what packing each page alone gives, in few encode calls."""
+        cfg, eng = _mk_engine(max_batch=2, max_len=32)
+        kv = eng.kv
+        seen = _record_packs(kv)
+        sealed_by = []
+        inner = kv._seal_from_device
+
+        def seal_from_device(layer, pid, rid):
+            sealed_by.append(rid)
+            inner(layer, pid, rid)
+
+        kv._seal_from_device = seal_from_device
+        rng = np.random.default_rng(4)
+        for rid, n in enumerate((7, 11)):      # both 3 past a page boundary
+            eng.submit(Request(rid=rid, prompt=rng.integers(
+                0, cfg.vocab_size, n).astype(np.int32), max_new_tokens=3))
+        eng.step()
+        assert sorted(set(sealed_by)) == [0, 1]
+        assert len(sealed_by) == 2 * len(kv.attn_layers)
+        _assert_packed_alone(kv, seen)
+        ks = eng.kv_stats()
+        assert ks["kv_pages_packed"] == len(seen) \
+            == (1 + 2 + 2) * len(kv.attn_layers)
+        # the second admission (the first seals before calibration) and
+        # the decode-path flush
+        assert 2 <= ks["kv_seal_encode_calls"] < ks["kv_pages_packed"]
+        assert not kv._pending
+
+    def test_calibration_mid_ingest_packs_once_without_nesting(self):
+        """A prompt long enough to calibrate its layers mid-ingest: the
+        pages sealed before and after calibration pack in one flush at
+        the end of the ingest, and no ``kv.seal`` span nests in another
+        (``admit.seal_ms`` sums them at any depth)."""
+        cfg, eng = _mk_engine(max_batch=1, max_len=32)
+        kv = eng.kv
+        seen = _record_packs(kv)
+        rng = np.random.default_rng(5)
+        eng.submit(Request(rid=0, prompt=rng.integers(
+            0, cfg.vocab_size, 13).astype(np.int32), max_new_tokens=2))
+        t0 = time.perf_counter()
+        calls0 = kv.traffic["kv_seal_encode_calls"]
+        eng.step()
+        win = spans.recorded(t0, time.perf_counter())
+        assert win.complete
+        by_id = {s.id: s for s in win.spans}
+        seals = [s for s in win.spans if s.name == "kv.seal"]
+        for s in seals:
+            p = s.parent
+            while p is not None and p in by_id:
+                assert by_id[p].name != "kv.seal"
+                p = by_id[p].parent
+        flushes = [s for s in seals if any(
+            c.parent == s.id and c.name == "kv.seal.encode"
+            for c in win.spans)]
+        assert len(flushes) == 1                 # the ingest's, none nested
+        assert kv.traffic["kv_seal_encode_calls"] - calls0 \
+            == len(M.seal_chunks(2 * 3 * len(kv.attn_layers)))
+        assert all(len(kv._packed[layer]) == 3 for layer in kv.attn_layers)
+        _assert_packed_alone(kv, seen)
+
+    def test_readahead_restore_packs_a_cold_page(self):
+        """A page spilled COLD, whose layer calibrates while it is
+        parked, comes back PACKED at the restore, as packing it alone
+        would make it, with readahead bytes of the packed page."""
+        cfg = apack_cfg()
+        kv = M.PagedKVCache(cfg, num_pages=64, page_size=4, calib_pages=2)
+        rng = np.random.default_rng(6)
+        h, dh, n = kv.pool.kv_heads, kv.pool.head_dim, kv.n_layers
+
+        def tok():
+            return (rng.integers(-127, 128, (n, h, dh)).astype(np.int8),
+                    rng.integers(-127, 128, (n, h, dh)).astype(np.int8),
+                    rng.uniform(0.01, 0.02, (n, h)).astype(np.float32),
+                    rng.uniform(0.01, 0.02, (n, h)).astype(np.float32))
+
+        kv.add_request(0)
+        for _ in range(4):
+            kv.append_token(0, *tok())
+        assert all(len(kv._cold[layer]) == 1 for layer in kv.attn_layers)
+        assert kv.spill_request(0) == len(kv.attn_layers)
+        kv.add_request(1)
+        for _ in range(4):                       # calibrates every layer
+            kv.append_token(1, *tok())
+        packed0 = kv.traffic["kv_pages_packed"]
+        seen = _record_packs(kv)
+        restored = kv.unspill_request(0)
+        assert not kv._pending
+        assert sorted(seen) == sorted(restored)
+        assert kv.traffic["kv_pages_packed"] - packed0 == len(restored)
+        _assert_packed_alone(kv, seen)
+        assert kv.traffic["kv_readahead_bytes"] == sum(
+            kv.pool.page_bytes(pid) for pid in restored)
+
+    @pytest.mark.parametrize("scheduler", ["sync", "async"])
+    def test_pending_queue_empty_after_every_public_call(self, scheduler):
+        """No caller of the cache sees a queued page: the queue is empty
+        whenever a public method returns to code outside the cache (a
+        public method it calls itself, such as ``evict_rolled``, may run
+        while pages wait for the flush)."""
+        cfg, eng = _mk_engine(max_batch=2, max_len=32, scheduler=scheduler)
+        kv = eng.kv
+        returned = []
+        depth = [0]
+        for name, _ in inspect.getmembers(M.PagedKVCache,
+                                          inspect.isfunction):
+            if name.startswith("_"):
+                continue
+            inner = getattr(kv, name)
+
+            def checked(*a, _inner=inner, _name=name, **k):
+                depth[0] += 1
+                try:
+                    return _inner(*a, **k)
+                finally:
+                    depth[0] -= 1
+                    if depth[0] == 0:
+                        assert not kv._pending, _name
+                        returned.append(_name)
+
+            setattr(kv, name, checked)
+        rng = np.random.default_rng(7)
+        reqs = [Request(rid=i, prompt=rng.integers(0, cfg.vocab_size, n)
+                        .astype(np.int32), max_new_tokens=6)
+                for i, n in enumerate((9, 6, 13))]
+        for r in reqs:
+            eng.submit(r)
+        eng.run_until_drained(max_steps=200)
+        assert all(r.done for r in reqs)
+        assert {"note_appended", "step_meta", "release"} <= set(returned)
+        assert eng.kv_stats()["kv_pages_packed"] > 0

@@ -174,6 +174,7 @@ def traced_run(tmp_path_factory):
     opts.python_tracer_level = 0
     opts.host_tracer_level = 1
     packed0 = eng.kv_stats()["kv_pages_packed"]
+    encodes0 = eng.kv_stats()["kv_seal_encode_calls"]
     tot0 = spans.totals()
     jax.profiler.start_trace(str(tdir), profiler_options=opts)
     t0 = time.perf_counter()
@@ -190,6 +191,7 @@ def traced_run(tmp_path_factory):
     return dict(eng=eng, reqs=reqs, calls=calls,
                 window=spans.recorded(t0, t1), counts=counts,
                 packed=eng.kv_stats()["kv_pages_packed"] - packed0,
+                encodes=eng.kv_stats()["kv_seal_encode_calls"] - encodes0,
                 xplane=xplane)
 
 
@@ -233,11 +235,27 @@ def test_decode_step_phases_nest_in_the_step(traced_run):
 
 
 def test_one_encode_span_per_packed_page(traced_run):
-    n = traced_run["counts"].get("kv.seal.encode", 0)
-    assert n == traced_run["packed"] > 0
-    assert traced_run["counts"]["kv.seal.crc"] >= n
-    assert traced_run["counts"]["kv.seal"] \
-        == traced_run["counts"]["kv.seal.requantize"] >= n
+    """Pages seal one by one (``kv.seal`` with one ``kv.seal.requantize``)
+    and pack in flushes (``kv.seal`` holding the batched encode calls,
+    one ``kv.seal.encode`` each, and one ``kv.seal.crc`` per page)."""
+    win, counts = traced_run["window"], traced_run["counts"]
+    children = {}
+    for s in win.spans:
+        children.setdefault(s.parent, []).append(s.name)
+    seals = [s for s in win.spans if s.name == "kv.seal"]
+    flushes = [s for s in seals if "kv.seal.encode" in children.get(s.id, [])]
+    per_page = [s for s in seals if s not in flushes]
+    assert counts["kv.seal.encode"] == traced_run["encodes"] > 0
+    assert counts["kv.seal"] == counts["kv.seal.requantize"] + len(flushes)
+    assert all(children[s.id] == ["kv.seal.requantize"] for s in per_page)
+    pages = [children[s.id].count("kv.seal.crc") for s in flushes]
+    assert sum(pages) == traced_run["packed"] > 0
+    assert counts["kv.seal.requantize"] >= traced_run["packed"]
+    for s, n in zip(flushes, pages):
+        assert children[s.id].count("kv.seal.encode") \
+            == len(M.seal_chunks(2 * n))
+    # the batching is real: some flush packs pages of several layers
+    assert max(pages) > 1
 
 
 def test_spans_add_no_transfer(traced_run):

@@ -20,7 +20,9 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import decompress_matmul as dm
 from repro.kernels.fused_page_attention import fused_page_attention_pallas
 from repro.kernels.paged_decode import gather_decode_pallas
-from repro.kernels.ref import ofs_capacity_words, sym_capacity_words
+from repro.kernels.ref import (ENCODER_ROW, encode_pages,
+                               ofs_capacity_words, sym_capacity_words)
+from repro.models.model import SEAL_CHUNK_LARGE, SEAL_CHUNK_SMALL
 
 I32, U32, F32 = jnp.int32, jnp.uint32, jnp.float32
 D_MODEL, D_FF = 2048, 6144
@@ -113,3 +115,12 @@ def test_paged_decode_compiles(one_chip):
     _compiled_kernel(gather_decode_pallas.lower(
         pl["sym_k"], pl["ofs_k"], pl["stored_k"], pages, pl["vm"],
         pl["ol"], pl["cum"], n_steps=E, interpret=False, table_idx=pages))
+
+
+@pytest.mark.parametrize("size", [SEAL_CHUNK_SMALL, SEAL_CHUNK_LARGE])
+def test_page_seal_encoder_compiles(one_chip, size):
+    """The page seal's batched encoder (a plain XLA program) at both of
+    its chunk shapes."""
+    a = lambda shape, dtype: _shape(one_chip, shape, dtype)  # noqa: E731
+    encode_pages.lower(a((size, N_STREAMS, E), jnp.uint8),
+                       a((size, ENCODER_ROW), I32)).compile()
