@@ -89,24 +89,9 @@ class Spans:
                 and not any(c <= a and b <= d for c, d in skip)]
 
 
-def program_config(model: dict):
-    from repro.models.config import ModelConfig
-    return ModelConfig(
-        name="qwen3", family="dense",
-        num_layers=model["num_hidden_layers"], d_model=model["hidden_size"],
-        num_heads=model["num_attention_heads"],
-        num_kv_heads=model["num_key_value_heads"],
-        head_dim=model["head_dim"], d_ff=model["intermediate_size"],
-        vocab_size=model["vocab_size"], qk_norm=True, mlp_variant="swiglu",
-        rope_theta=float(model["rope_theta"]),
-        tie_embeddings=model["tie_word_embeddings"],
-        norm_eps=float(model["rms_norm_eps"]), param_dtype="float32",
-        kv_cache_dtype=model["serving"]["kv"])
-
-
 def check_layout(cfg, params) -> None:
-    """The tree ``weights.program_params`` builds has the program's own
-    layout (shapes only are read from the program)."""
+    """The tree the architecture's ``program_params`` builds has the
+    program's own layout (shapes only are read from the program)."""
     import jax
     from repro.models import model as M
     want = jax.eval_shape(lambda: M.init_params(cfg, jax.random.PRNGKey(0)))
@@ -259,10 +244,10 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     log(f"device {device.device_kind} x{len(jax.devices())}; compile cache "
         f"{cache_dir}")
 
-    cfg = program_config(model)
+    cfg = cell.arch.program_config(model)
     int8 = model["serving"]["weights"] == "apack-int8"
     t0 = time.perf_counter()
-    params = weight_gen.program_params(weight_gen.make(model, seed, int8=int8))
+    params = cell.arch.program_params(weight_gen.make(model, seed, int8=int8))
     check_layout(cfg, params)
     jax.block_until_ready(params)
     t1 = time.perf_counter()

@@ -1,8 +1,8 @@
 """fused_page_attention_roofline: least time of the window's paged decode
 attention over the device time of the ``fused_page_attention`` kernel's
-trace events.  FLOPs: 4*H*dh per cached key per layer for every decoded
-token; bytes: the KV payload the window's steps read (``kv_stats``
-``kv_read_bytes``, coded size of PACKED pages) plus each query and output.
+trace events.  FLOPs and bytes: the architecture's ``page_attention_cost``
+over every decoded token's cached keys, with the KV payload the window's
+steps read (``kv_stats`` ``kv_read_bytes``, coded size of PACKED pages).
 The least time is the larger of FLOPs over the bf16 peak and bytes over HBM
 bandwidth, taken over the whole window."""
 import costs

@@ -1,20 +1,35 @@
 """Find a cell's files by the names in ``BENCHMARK.json``.
 
 A cell names a configuration (``configs/<config>.json``) and a traffic mix
-(``traffic/<traffic>.json``); per-layer metrics are readers in
+(``traffic/<traffic>.json``); the configuration's ``model_type``, the key of
+its published config, names its architecture module
+(``archs/<model_type>.py``); per-layer metrics are readers in
 ``metrics/<name>.py``; device peaks are in ``peaks.json``, keyed by
-``device_kind``.  Adding a cell, a configuration, a mix or a metric adds
-files and entries and edits none.
+``device_kind``.  Adding a cell, a configuration, an architecture, a mix or
+a metric adds files and entries and edits none.
+
+An architecture module holds everything that depends on the architecture,
+under these names: ``program_config(model)``, the program's ``ModelConfig``;
+``WEIGHT_KEYS`` and ``init(model, int8, key)``, the reference's seeded
+weights; ``program_params(w)``, the program's parameter tree built from
+them; ``REFERENCE_KEYS``, ``forward(model, mode, w, tokens)`` and
+``logits(model, mode, w, x)``, the plain forward pass and LM head
+(``reference.py``); ``projection_params``, ``decode_token_flops``,
+``prefill_flops``, ``compressed_matmul_cost`` and ``page_attention_cost``
+(``costs.py``); and ``TINY`` and ``TINY_LIMIT``, the CPU tests' size and
+limit.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]                 # the checkout: BENCHMARK.json, src/
+ARCHS = HERE / "archs"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -23,6 +38,7 @@ class Cell:
     chips: int
     config_name: str
     config: dict                       # configs/<config>.json
+    arch: object                       # archs/<model_type>.py
     traffic_name: str
     traffic: dict                      # traffic/<traffic>.json
     end_to_end: list                   # metric entries this cell reports
@@ -32,6 +48,28 @@ class Cell:
 def _load(path: Path) -> dict:
     with open(path) as f:
         return json.load(f)
+
+
+def _exec(name: str, path: Path):
+    mod_spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod
+
+
+@functools.cache
+def load_arch(model_type: str):
+    """The module ``archs/<model_type>.py``, loaded once."""
+    path = ARCHS / f"{model_type}.py"
+    if not path.is_file():
+        raise SystemExit(f"no architecture module {path} for model_type "
+                         f"{model_type!r}")
+    return _exec(f"arch_{model_type}", path)
+
+
+def arch_of(model: dict):
+    """The architecture module of a configuration's dict."""
+    return load_arch(model["model_type"])
 
 
 def _reports(metric: dict, cell: str) -> bool:
@@ -46,9 +84,15 @@ def load_cell(name: str, bench_path: Path | None = None) -> Cell:
                          f"{sorted(cells)}")
     w = cells[name]
     configs = {c["name"]: c for c in bench["configs"]}
+    config_path = ROOT / configs[w["config"]]["file"]
+    config = _load(config_path)
+    if "model_type" not in config:
+        raise SystemExit(f"{config_path} has no \"model_type\", which names "
+                         f"its module {ARCHS}/<model_type>.py")
     return Cell(
         name=name, chips=int(w["chips"]),
-        config_name=w["config"], config=_load(ROOT / configs[w["config"]]["file"]),
+        config_name=w["config"], config=config,
+        arch=load_arch(config["model_type"]),
         traffic_name=w["traffic"],
         traffic=_load(HERE / "traffic" / f"{w['traffic']}.json"),
         end_to_end=[m for m in bench["end_to_end"] if _reports(m, name)],
@@ -62,9 +106,5 @@ def load_peaks(device_kind: str) -> dict | None:
 
 def load_reader(metric_name: str):
     """The ``read(ctx)`` function of ``metrics/<metric_name>.py``."""
-    path = HERE / "metrics" / f"{metric_name}.py"
-    mod_spec = importlib.util.spec_from_file_location(
-        f"metric_{metric_name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read
+    return _exec(f"metric_{metric_name.replace('.', '_')}",
+                 HERE / "metrics" / f"{metric_name}.py").read

@@ -14,18 +14,9 @@ import pytest
 import harness
 import spec
 
-TINY = dict(hidden_size=128, intermediate_size=256, num_hidden_layers=2,
-            num_attention_heads=4, num_key_value_heads=2, head_dim=32,
-            vocab_size=512)
 TINY_MIX = dict(slots=4, max_len=96,
                 prompt_tokens={"dist": "loguniform", "min": 20, "max": 64},
                 output_tokens={"dist": "loguniform", "min": 8, "max": 32})
-# The limit at this size, set from its readings on the CPU (seeds 11-14):
-# sound runs read at most 0.0233, the controls at least 0.224 (fp8
-# projections) and 1.10 (int4 weights).  The cells' own limits are set for
-# their size.
-TINY_LIMIT = 0.1
-CELLS = ["qwen3-1.7b-packed.decode", "qwen3-1.7b-densew.chat"]
 # The packed cell is out of BENCHMARK.json until its slow steps are
 # understood; these entries add it back, as data alone.
 PACKED = {
@@ -35,6 +26,11 @@ PACKED = {
                   "config": "qwen3-1.7b-packed", "traffic": "decode",
                   "chips": 1},
 }
+BENCHMARK_CELLS = [w["name"] for w in json.loads(
+    (spec.ROOT / "BENCHMARK.json").read_text())["workloads"]]
+# every cell of BENCHMARK.json, and the packed one
+CELLS = [PACKED["workloads"]["name"]] + [
+    c for c in BENCHMARK_CELLS if c != PACKED["workloads"]["name"]]
 
 
 @pytest.fixture(scope="module")
@@ -50,10 +46,11 @@ def bench(tmp_path_factory):
 
 
 def tiny_cell(name, bench):
+    """The cell at its architecture's tiny size and limit."""
     cell = spec.load_cell(name, bench)
     cfg = copy.deepcopy(cell.config)
-    cfg.update(TINY)
-    cfg["limits"] = {"served_logit_gap": TINY_LIMIT}
+    cfg.update(cell.arch.TINY)
+    cfg["limits"] = {"served_logit_gap": cell.arch.TINY_LIMIT}
     mix = dict(copy.deepcopy(cell.traffic), **TINY_MIX)
     return dataclasses.replace(cell, config=cfg, traffic=mix)
 
@@ -107,7 +104,8 @@ def test_control_is_not_correct(name, bench):
 
 def test_no_tpu_no_result():
     proc = subprocess.run(
-        [sys.executable, str(spec.HERE / "run.py"), "--workload", CELLS[1],
+        [sys.executable, str(spec.HERE / "run.py"),
+         "--workload", BENCHMARK_CELLS[0],
          "--seed", "1", "--seconds", "1", "--trace", "0"],
         capture_output=True, text=True, timeout=300,
         env={"JAX_PLATFORMS": "cpu", "PATH": "/usr/bin:/bin"})

@@ -12,7 +12,7 @@ import harness
 import spec
 import traffic as traffic_gen
 import weights as weight_gen
-from test_run_cpu import TINY, TINY_MIX
+from test_run_cpu import TINY_MIX
 
 CELL = "qwen3-1.7b-densew.chat"
 READERS = ["admit.prefill_ms", "admit.seal_ms",
@@ -25,11 +25,11 @@ def ctx():
     with the harness's spans wrapped as a traced run wraps them, long
     enough to hold two admissions."""
     cell = spec.load_cell(CELL)
-    model = dict(copy.deepcopy(cell.config), **TINY)
+    model = dict(copy.deepcopy(cell.config), **cell.arch.TINY)
     mix = dict(copy.deepcopy(cell.traffic), **TINY_MIX)
     seed = 2 ** 31 + 17
-    cfg = harness.program_config(model)
-    params = weight_gen.program_params(
+    cfg = cell.arch.program_config(model)
+    params = cell.arch.program_params(
         weight_gen.make(model, seed, int8=False))
     eng = harness.build_engine(cfg, params, model, mix)
     requests = traffic_gen.generate(mix, model["vocab_size"], seed)
